@@ -55,6 +55,9 @@ def main() -> None:
                          "fig8_scaling §2.4 matrix (e.g. 1.0,0.5,0.25)")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.superstep:
         from benchmarks import superstep_bench
         rows = superstep_bench.run(quick=not args.full)

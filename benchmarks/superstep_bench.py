@@ -12,8 +12,8 @@ run reporting
                               stages; 0 for the serialized all_to_all);
   * `step_time_modeled_s`   — per-superstep roofline: HBM time for the home
                               materializations + the UNHIDDEN fraction of
-                              link time (launch.perf constants — no TPU
-                              wall clock exists in this container);
+                              link time (launch.perf constants — a model,
+                              not a chip measurement);
   * `materializations_*`    — home-shaped HBM array materializations per
                               superstep from the traced jaxpr, fused vs
                               unfused apply (the §2.3.2 claim: strictly

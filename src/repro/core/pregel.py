@@ -54,6 +54,9 @@ class PregelResult:
     graph: Graph
     supersteps: int
     metrics: list[dict]     # per-superstep engine metrics
+    # the jitted superstep the run compiled: `step.lower(graph,
+    # transport=...)` gives its program for inspection (HLO, memory).
+    step: Callable | None = None
 
 
 def _superstep(g: Graph, tstate=None, *, vprog, send_msg, gather,
@@ -355,7 +358,8 @@ def pregel(
                     break
     if ring is not None:
         g = ring.materialize(g)    # exit fully resident, like the carry in
-    return PregelResult(graph=g, supersteps=steps, metrics=all_metrics)
+    return PregelResult(graph=g, supersteps=steps, metrics=all_metrics,
+                        step=step)
 
 
 def pregel_fused(

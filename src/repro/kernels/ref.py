@@ -50,8 +50,9 @@ from .triplet import SCALE_GROUP as _SCALE_GROUP  # noqa: E402
 def _dequant_rows(xf: jnp.ndarray, xscale: jnp.ndarray) -> jnp.ndarray:
     """Apply per-SCALE_GROUP-row pow2 exponents to an (exactly upcast) f32
     staging matrix — the oracle's counterpart of the kernel's in-VMEM
-    `_spread_scale_tile` dequant.  Same values, same multiply, so the two
-    paths stay bit-identical (§2.4)."""
+    dequant (`triplet._gather`, which gathers payload and exponent per
+    edge).  Same values, same multiply, so the two paths stay
+    bit-identical (§2.4)."""
     s = xf.shape[0]
     sc = xscale.astype(jnp.float32).reshape(xscale.shape[0], -1)
     sp = jnp.repeat(sc, _SCALE_GROUP, axis=0)[:s]

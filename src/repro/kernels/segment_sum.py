@@ -31,6 +31,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .triplet import mxu_dot
 
 
 def _kernel(lo_ref, hi_ref, act_ref, ids_ref, msgs_ref, out_ref):
@@ -44,24 +47,19 @@ def _kernel(lo_ref, hi_ref, act_ref, ids_ref, msgs_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     vb = out_ref.shape[0]
-    lo = lo_ref[0]          # first segment id present in edge block j
-    hi = hi_ref[0]          # last segment id present in edge block j
-    active = act_ref[0]     # any active (non-masked) edge in block j?
+    lo = lo_ref[j]          # first segment id present in edge block j
+    hi = hi_ref[j]          # last segment id present in edge block j
+    active = act_ref[j]     # any active (non-masked) edge in block j?
 
     band_hit = jnp.logical_and(hi >= i * vb, lo < (i + 1) * vb)
 
-    @pl.when(jnp.logical_and(band_hit, active))
+    @pl.when(jnp.logical_and(band_hit, active != 0))
     def _accumulate():
-        ids = ids_ref[...]                                   # [Eb] int32
-        local = ids - i * vb                                 # slot within block
-        cols = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], vb), 1)
-        onehot = (local[:, None] == cols).astype(jnp.float32)  # [Eb, Vb]
+        ids = ids_ref[...]                                   # [1, Eb] int32
+        rows = jax.lax.broadcasted_iota(jnp.int32, (vb, ids.shape[1]), 0)
+        onehot_t = (ids - i * vb == rows).astype(jnp.float32)  # [Vb, Eb]
         msgs = msgs_ref[...].astype(jnp.float32)             # [Eb, D]
-        out_ref[...] += jax.lax.dot_general(
-            onehot, msgs,
-            dimension_numbers=(((0,), (0,)), ((), ())),      # onehotᵀ @ msgs
-            preferred_element_type=jnp.float32,
-        )
+        out_ref[...] += mxu_dot(onehot_t, msgs)
 
 
 @functools.partial(
@@ -80,7 +78,7 @@ def segment_sum(
     """Segment-sum with f32 accumulation.  ids outside [0, num_segments) drop."""
     e, d = msgs.shape
     eb = min(edge_block, max(e, 8))
-    vb = min(vertex_block, max(num_segments, 8))
+    vb = min(vertex_block, -(-max(num_segments, 8) // 8) * 8)
 
     # Pad E to a multiple of eb and V to a multiple of vb.
     e_pad = (-e) % eb
@@ -93,24 +91,29 @@ def segment_sum(
     n_eb = (e + e_pad) // eb
     n_vb = v_out // vb + 1   # +1 out-of-band block swallowing padding ids
 
-    ids2 = ids.reshape(n_eb, eb)
-    lo = ids2.min(axis=1).astype(jnp.int32)
-    hi = ids2.max(axis=1).astype(jnp.int32)
-    act = (ids2 < num_segments).any(axis=1)
+    # per-edge-block band and skip flags ride scalar prefetch (SMEM); ids are
+    # laid along lanes, [n_eb, 1, eb], so each block spans the array's minor
+    # two dimensions.
+    ids3 = ids.reshape(n_eb, 1, eb)
+    lo = ids3.min(axis=(1, 2)).astype(jnp.int32)
+    hi = ids3.max(axis=(1, 2)).astype(jnp.int32)
+    act = (ids3 < num_segments).any(axis=(1, 2)).astype(jnp.int32)
 
-    out = pl.pallas_call(
-        _kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,                                # lo, hi, active
         grid=(n_vb, n_eb),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (j,)),            # lo
-            pl.BlockSpec((1,), lambda i, j: (j,)),            # hi
-            pl.BlockSpec((1,), lambda i, j: (j,)),            # active
-            pl.BlockSpec((eb,), lambda i, j: (j,)),           # ids
-            pl.BlockSpec((eb, d), lambda i, j: (j, 0)),       # msgs
+            pl.BlockSpec((pl.Squeezed(), 1, eb),
+                         lambda i, j, lo_, hi_, a_: (j, 0, 0)),   # ids
+            pl.BlockSpec((eb, d), lambda i, j, lo_, hi_, a_: (j, 0)),  # msgs
         ],
-        out_specs=pl.BlockSpec((vb, d), lambda i, j: (i, 0)),
+        out_specs=pl.BlockSpec((vb, d), lambda i, j, lo_, hi_, a_: (i, 0)),
+    )
+    out = pl.pallas_call(
+        _kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_vb * vb, d), jnp.float32),
         interpret=interpret,
-    )(lo, hi, act, ids, m)
+    )(lo, hi, act, ids3, m)
 
     return out[:num_segments].astype(msgs.dtype)

@@ -40,14 +40,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .triplet import (DEFAULT_EDGE_BLOCK, DEFAULT_VERTEX_BLOCK,
-                      REDUCE_IDENTITY, segmented_reduce_mxu)
+                      REDUCE_IDENTITY, chunk_rows, mxu_dot,
+                      segmented_reduce_mxu)
 
 
-def _make_apply_kernel(apply_fn: Callable, reduce: str, dm: int):
+def _make_apply_kernel(apply_fn: Callable, reduce: str):
     ident = REDUCE_IDENTITY[reduce]
 
     def kernel(cout_ref, act_ref,
-               sloc_ref, live_ref, pay_ref,
+               edge_ref, pay_ref,
                xv_ref, vid_ref, vm_ref,
                newv_ref, chg_ref, acc_ref, cnt_ref):
         i = pl.program_id(0)      # home vertex block
@@ -60,26 +61,26 @@ def _make_apply_kernel(apply_fn: Callable, reduce: str, dm: int):
             cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
         mine = cout_ref[c] == i
-        @pl.when(jnp.logical_and(mine, act_ref[c]))
+        @pl.when(jnp.logical_and(mine, act_ref[c] != 0))
         def _accumulate():
             vb = acc_ref.shape[0]
-            eb = sloc_ref.shape[0]
-            live = live_ref[...]                                 # [Eb] 0/1
-            pay = pay_ref[...].astype(jnp.float32)               # [Eb, Dm]
-            cols = jax.lax.broadcasted_iota(jnp.int32, (eb, vb), 1)
-            oh = (sloc_ref[...][:, None] == cols).astype(jnp.float32)
-            oh_live = oh * live[:, None]
-            cnt_ref[...] += jnp.sum(oh_live, axis=0)[:, None]
+            row = edge_ref[...]         # [2, Eb] home slot, live
+            col = row.T                                          # [Eb, 2]
+            eb = col.shape[0]
+            live = col[:, 1:2] > 0                               # [Eb, 1]
+            pay = pay_ref[...].T                                 # [Eb, Dm]
+            rows = jax.lax.broadcasted_iota(jnp.int32, (vb, eb), 0)
+            oh_t = (row[0:1, :] == rows).astype(jnp.float32)     # [Vb, Eb]
+            oh_live = oh_t * (row[1:2, :] > 0).astype(jnp.float32)
+            cnt_ref[...] += jnp.sum(oh_live, axis=1, keepdims=True)
             if reduce == "sum":
-                pay = jnp.where(live[:, None] > 0.0, pay, 0.0)
-                acc_ref[...] += jax.lax.dot_general(             # scatter-add
-                    oh_live, pay, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+                acc_ref[...] += mxu_dot(oh_live,                 # scatter-add
+                                        jnp.where(live, pay, 0.0))
             else:
                 sel = jnp.minimum if reduce == "min" else jnp.maximum
-                vals = jnp.where(live[:, None] > 0.0, pay, ident)
-                red = segmented_reduce_mxu(
-                    vals, sloc_ref[...][:, None], reduce, ident, oh)
+                vals = jnp.where(live, pay, ident)
+                red = segmented_reduce_mxu(vals, col[:, 0:1], row[0:1, :],
+                                           reduce, ident, oh_t)
                 acc_ref[...] = sel(acc_ref[...], red)
 
         # the LAST chunk's visit to this block closes the combine; the apply
@@ -146,17 +147,17 @@ def fused_apply(
     oob = pc >= r
     cs = jnp.where(oob, vb, sp[pc] - (chunk_out * vb)[:, None]).astype(jnp.int32)
     clive = lp[pc] & ~oob
-    cpay = payp[pc]                               # padding row -> zeros
-    act = clive.any(axis=1)                       # chunk skip flag (dynamic)
-    clive_f = clive.astype(jnp.float32)
+    cedge = chunk_rows([cs], clive)
+    cpay = jnp.swapaxes(payp[pc], 1, 2)           # padding row -> zeros
+    act = clive.any(axis=1).astype(jnp.int32)     # chunk skip flag (dynamic)
 
+    sq = pl.Squeezed()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                    # chunk_out + act
         grid=(n_vb, n_chunks),
         in_specs=[
-            pl.BlockSpec((1, eb), lambda i, c, co_, a: (c, 0)),
-            pl.BlockSpec((1, eb), lambda i, c, co_, a: (c, 0)),
-            pl.BlockSpec((1, eb, dm), lambda i, c, co_, a: (c, 0, 0)),
+            pl.BlockSpec((sq, 2, eb), lambda i, c, co_, a: (c, 0, 0)),
+            pl.BlockSpec((sq, dm, eb), lambda i, c, co_, a: (c, 0, 0)),
             pl.BlockSpec((vb, dxv), lambda i, c, co_, a: (i, 0)),
             pl.BlockSpec((vb, 1), lambda i, c, co_, a: (i, 0)),
             pl.BlockSpec((vb, 1), lambda i, c, co_, a: (i, 0)),
@@ -169,18 +170,11 @@ def fused_apply(
                         pltpu.VMEM((vb, 1), jnp.float32)],
     )
 
-    inner = _make_apply_kernel(apply_fn, reduce, dm)
-
-    def kern(co_ref, a_ref, sloc_ref, live_ref, pay_ref,
-             xv_ref, vid_ref, vm_ref, newv_ref, chg_ref, acc_ref, cnt_ref):
-        inner(co_ref, a_ref, sloc_ref[0], live_ref[0], pay_ref[0],
-              xv_ref, vid_ref, vm_ref, newv_ref, chg_ref, acc_ref, cnt_ref)
-
     newv, chg = pl.pallas_call(
-        kern,
+        _make_apply_kernel(apply_fn, reduce),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((v_pad, dxv), jnp.float32),
                    jax.ShapeDtypeStruct((v_pad, 1), jnp.float32)],
         interpret=interpret,
-    )(chunk_out, act, cs, clive_f, cpay, xp, vidp, vmp)
+    )(chunk_out, act, cedge, cpay, xp, vidp, vmp)
     return newv[:num_slots], chg[:num_slots, 0]

@@ -189,66 +189,97 @@ def flatten_tiles(tiles, *, e_blk: int, n_vb: int) -> dict:
 # ----------------------------------------------------------------------------
 # Kernel
 # ----------------------------------------------------------------------------
-def segmented_reduce_mxu(vals, slot, reduce: str, ident, oh_out):
+# Every one-hot matmul runs at f32 contract precision: a gather or scatter
+# through the MXU must land each value exactly (CC stages int32 labels
+# through these; the chip's default bf16 pass would round them above 256).
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def mxu_dot(a, b):
+    """[M, K] @ [K, N] at f32 contract precision, f32 accumulate."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def chunk_rows(slots, live) -> jnp.ndarray:
+    """Per-chunk edge scalars [n_chunks, len(slots)+1, eb] int32: each slot
+    stream, then live, laid along lanes.  One block spans the array's minor
+    two dimensions (legal under the TPU's (8, 128) tiling for any chunk size)
+    and stays lane-dense in HBM; the kernels transpose it to per-edge columns
+    in VMEM."""
+    return jnp.stack(list(slots) + [live.astype(jnp.int32)], axis=1)
+
+
+def segmented_reduce_mxu(vals, slot_col, slot_row, reduce: str, ident,
+                         oh_t):
     """Block-local segment min/max via the segmented-scan trick (MXU path).
 
-    vals   [Eb, Dm] f32, dead rows ALREADY substituted with `ident`
-    slot   [Eb, 1]  int32 output slots; equal-slot rows must be CONTIGUOUS
-                    (build_triplet_tiles sorts each chunk by aggregation slot,
-                    padding rows at the tail)
-    oh_out [Eb, Vb] f32 one-hot of slot against the block's columns (0 rows
-                    for OOB/padding slots)
+    vals     [Eb, Dm] f32, dead rows ALREADY substituted with `ident`
+    slot_col [Eb, 1]  int32 output slots; equal-slot rows must be CONTIGUOUS
+                      (build_triplet_tiles sorts each chunk by aggregation
+                      slot, padding rows at the tail)
+    slot_row [1, Eb]  the same slots laid along lanes
+    oh_t     [Vb, Eb] f32 transposed one-hot of slot against the block's
+                      rows (0 columns for OOB/padding slots)
 
     A Hillis–Steele segmented inclusive prefix scan (log2(Eb) static steps of
-    shift + slot-guarded select, pure VPU elementwise on the [Eb, Dm] tile)
-    leaves every segment's FULL reduction at its last row; the boundary
-    one-hot then has exactly one nonzero per output column, so a single
+    sublane rotate + slot-guarded select, pure VPU elementwise on the
+    [Eb, Dm] tile) leaves every segment's FULL reduction at its last row; the
+    boundary one-hot then has exactly one nonzero per output row, so a single
     [Vb, Eb] @ [Eb, Dm] matmul lands the per-slot results on the MXU — exact,
-    because each output element sums exactly one scanned term.  This replaces
-    the old per-column masked VPU reduce, which materialised Dm full [Eb, Vb]
-    masks and kept CC/SSSP off the MXU.
+    because each output element sums exactly one scanned term.
     """
     sel = jnp.minimum if reduce == "min" else jnp.maximum
     eb = vals.shape[0]
-    acc, seg = vals, slot
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (eb, 1), 0)
+    acc = vals
     shift = 1
     while shift < eb:                                 # log2(Eb) static steps
-        prev = jnp.concatenate(
-            [jnp.full((shift,) + acc.shape[1:], ident, acc.dtype),
-             acc[:-shift]], axis=0)
-        pseg = jnp.concatenate(
-            [jnp.full((shift, 1), -1, seg.dtype), seg[:-shift]], axis=0)
-        acc = jnp.where(pseg == seg, sel(acc, prev), acc)
+        prev = pltpu.roll(acc, shift, 0)
+        pseg = pltpu.roll(slot_col, shift, 0)
+        same = jnp.logical_and(pseg == slot_col, row_id >= shift)
+        acc = jnp.where(same, sel(acc, prev), acc)
         shift *= 2
-    nxt = jnp.concatenate(
-        [seg[1:], jnp.full((1, 1), -2, seg.dtype)], axis=0)
-    last = (seg != nxt).astype(jnp.float32)           # [Eb, 1] segment ends
-    oh_last = oh_out * last                           # ≤1 nonzero per column
-    red = jax.lax.dot_general(oh_last, acc, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)  # [Vb, Dm]
-    present = jnp.sum(oh_last, axis=0)[:, None] > 0.0
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, eb), 1)
+    nxt = pltpu.roll(slot_row, eb - 1, 1)             # slot of the next edge
+    last = jnp.logical_or(slot_row != nxt, lane == eb - 1)   # segment ends
+    oh_last = oh_t * last.astype(jnp.float32)         # ≤1 nonzero per row
+    red = mxu_dot(oh_last, acc)                       # [Vb, Dm]
+    present = jnp.sum(oh_last, axis=1, keepdims=True) > 0.0
     return jnp.where(present, red, ident)
 
 
-def _spread_scale_tile(scale_ref, vb: int) -> jnp.ndarray:
-    """[vb//SCALE_GROUP, D] per-block exponent tile -> [vb, D] f32 pow2
-    multipliers, each scale row covering its SCALE_GROUP payload rows.
-    exp2 of an int exponent in [-126, 126] is exact in f32, so multiplying
-    the (exactly upcast) narrow payload by this is the same dequant
-    `wire.decode_resident` performs — bit-identical staging (§2.4)."""
-    sc = scale_ref[...].astype(jnp.float32)
-    d = sc.shape[-1]
-    sc = jnp.broadcast_to(sc[:, None, :],
-                          (sc.shape[0], SCALE_GROUP, d)).reshape(vb, d)
-    return jnp.exp2(sc)
+def _gather(x_ref, sc_ref, slot, cols):
+    """Gather vertex rows for each edge: one-hot [Eb, Vb] @ tile [Vb, D].
+
+    With a narrow-RESIDENT tile (§2.4) the payload arrives encoded and
+    `sc_ref` holds one E8M0 exponent row per SCALE_GROUP payload rows: the
+    exponent is gathered at group granularity and applied after the payload
+    gather.  Each one-hot row picks exactly one term, so this equals
+    dequantizing the tile first (`wire.decode_resident`) bit for bit."""
+    oh = (slot == cols).astype(jnp.float32)
+    v = mxu_dot(oh, x_ref[...].astype(jnp.float32))
+    if sc_ref is not None:
+        sb = sc_ref.shape[0]
+        grp = jax.lax.broadcasted_iota(jnp.int32, (slot.shape[0], sb), 1)
+        oh_g = (slot // SCALE_GROUP == grp).astype(jnp.float32)
+        v = v * jnp.exp2(mxu_dot(oh_g, sc_ref[...].astype(jnp.float32)))
+    return v
 
 
-def _make_kernel(tile_fn: Callable, reduce: str, dm: int, have_scale: bool):
+def _make_kernel(tile_fn: Callable, reduce: str, use_src: bool,
+                 use_dst: bool, have_scale: bool):
     ident = REDUCE_IDENTITY[reduce]
 
-    def kernel(cout_ref, csrc_ref, cdst_ref, act_ref,
-               sloc_ref, dloc_ref, oloc_ref, live_ref, ev_ref,
-               xs_ref, xd_ref, ss_ref, ds_ref, out_ref, cnt_ref):
+    def kernel(cout_ref, csrc_ref, cdst_ref, act_ref, edge_ref, ev_ref,
+               *refs):
+        refs = list(refs)
+        xs_ref = refs.pop(0) if use_src else None
+        xd_ref = refs.pop(0) if use_dst else None
+        ss_ref = refs.pop(0) if have_scale and use_src else None
+        ds_ref = refs.pop(0) if have_scale and use_dst else None
+        out_ref, cnt_ref = refs
         i = pl.program_id(0)      # aggregation-side block
         c = pl.program_id(1)      # chunk
 
@@ -260,51 +291,41 @@ def _make_kernel(tile_fn: Callable, reduce: str, dm: int, have_scale: bool):
         mine = cout_ref[c] == i
         # chunk skip (§4.6): a chunk whose edges are all dead — masked,
         # skipStale, or padding — never touches the tile pair.
-        @pl.when(jnp.logical_and(mine, act_ref[c]))
+        @pl.when(jnp.logical_and(mine, act_ref[c] != 0))
         def _accumulate():
             vb = out_ref.shape[0]
-            eb = sloc_ref.shape[0]
-            live = live_ref[...]                                 # [Eb] 0/1
+            row = edge_ref[...]         # [4, Eb] src / dst / out slot, live
+            col = row.T                                          # [Eb, 4]
+            eb = col.shape[0]
+            live = col[:, 3:4] > 0                               # [Eb, 1]
             cols = jax.lax.broadcasted_iota(jnp.int32, (eb, vb), 1)
-            oh_s = (sloc_ref[...][:, None] == cols).astype(jnp.float32)
-            oh_d = (dloc_ref[...][:, None] == cols).astype(jnp.float32)
-            xs = xs_ref[...].astype(jnp.float32)
-            xd = xd_ref[...].astype(jnp.float32)
-            if have_scale:
-                # narrow-RESIDENT mirror tile (§2.4): the payload arrived in
-                # its encoded dtype; dequantize HERE, in VMEM, so the f32
-                # copy never exists in HBM.
-                xs = xs * _spread_scale_tile(ss_ref, vb)
-                xd = xd * _spread_scale_tile(ds_ref, vb)
-            sv = jax.lax.dot_general(                            # gather src
-                oh_s, xs, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)              # [Eb, Dx]
-            dv = jax.lax.dot_general(                            # gather dst
-                oh_d, xd, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            msgs = tile_fn(sv, ev_ref[...].astype(jnp.float32), dv)  # [Eb, Dm]
-            # dead rows (padding / masked / stale) gathered ZERO endpoint
-            # values, so the UDF may have produced NaN/inf there (0/0 in
-            # PageRank's pr/deg).  Mask by SUBSTITUTION before any matmul —
-            # multiplying by the 0/1 one-hot would turn 0·NaN into NaN and
-            # poison the whole output block.
-            msgs = jnp.where(live[:, None] > 0.0, msgs, 0.0)
+            zero = jnp.zeros((eb, 1), jnp.float32)
+            sv = (_gather(xs_ref, ss_ref, col[:, 0:1], cols)
+                  if use_src else zero)                          # [Eb, Dx]
+            dv = (_gather(xd_ref, ds_ref, col[:, 1:2], cols)
+                  if use_dst else zero)
+            ev = ev_ref[...].astype(jnp.float32).T               # [Eb, De]
+            msgs = tile_fn(sv, ev, dv)                           # [Eb, Dm]
 
-            oh_o = (oloc_ref[...][:, None] == cols).astype(jnp.float32)
-            oh_live = oh_o * live[:, None]                       # [Eb, Vb]
-            cnt_ref[...] += jnp.sum(oh_live, axis=0)[:, None]
+            rows = jax.lax.broadcasted_iota(jnp.int32, (vb, eb), 0)
+            oh_t = (row[2:3, :] == rows).astype(jnp.float32)     # [Vb, Eb]
+            oh_live = oh_t * (row[3:4, :] > 0).astype(jnp.float32)
+            cnt_ref[...] += jnp.sum(oh_live, axis=1, keepdims=True)
             if reduce == "sum":
-                out_ref[...] += jax.lax.dot_general(             # scatter-add
-                    oh_live, msgs, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+                # dead rows (padding / masked / stale) gathered ZERO endpoint
+                # values, so the UDF may have produced NaN/inf there (0/0 in
+                # PageRank's pr/deg).  Mask by SUBSTITUTION before the matmul
+                # — multiplying by the 0/1 one-hot would turn 0·NaN into NaN
+                # and poison the whole output block.
+                out_ref[...] += mxu_dot(oh_live, jnp.where(live, msgs, 0.0))
             else:
                 sel = jnp.minimum if reduce == "min" else jnp.maximum
                 # dead rows keep their REAL slots but carry the identity, so
                 # they never perturb a segment's min/max; padding rows (slot
-                # == vb) match no column of the one-hot.
-                vals = jnp.where(live[:, None] > 0.0, msgs, ident)
-                red = segmented_reduce_mxu(
-                    vals, oloc_ref[...][:, None], reduce, ident, oh_o)
+                # == vb) match no row of the one-hot.
+                vals = jnp.where(live, msgs, ident)
+                red = segmented_reduce_mxu(vals, col[:, 2:3], row[2:3, :],
+                                           reduce, ident, oh_t)
                 out_ref[...] = sel(out_ref[...], red)            # [Vb, Dm]
 
     return kernel
@@ -341,16 +362,14 @@ def fused_triplet(
     """out[v] = reduce_{live e: out(e)=v} tile_fn(x[src(e)], ev[e], x[dst(e)])
 
     use_src / use_dst: whether tile_fn reads that endpoint's values.  An
-    unused side streams a width-1 zero tile instead of the packed mirror
-    matrix, halving vertex-tile VMEM/DMA for one-sided messages (PageRank
-    reads only src) — tile_fn must not touch the dummy (the engine's
+    unused side streams nothing: tile_fn receives a width-1 zero column in
+    its place (PageRank reads only src) and must not touch it (the engine's
     side-aware unpack guarantees this).
 
     Returns (out [S, dm] f32 — reduce identity at empty slots,
              cnt [S] f32 — live message count per slot).
     """
     e = src_slot.shape[0]
-    dx = max(x.shape[1], 1)
     de = max(ev.shape[1], 1)
     perm = jnp.asarray(tiles["perm"])
     chunk_out = jnp.asarray(tiles["chunk_out"])
@@ -365,31 +384,23 @@ def fused_triplet(
     # HBM/DMA traffic while the accumulator math is unchanged.
     xp = jnp.pad(x.reshape(x.shape[0], -1),
                  ((0, v_pad - x.shape[0]), (0, max(1 - x.shape[1], 0))))
-    dummy = jnp.zeros((v_pad, 1), jnp.float32)
-    xs_in, dxs = (xp, dx) if use_src else (dummy, 1)
-    xd_in, dxd = (xp, dx) if use_dst else (dummy, 1)
+    dx = xp.shape[1]
 
     # narrow-resident scale plane: one exponent row per SCALE_GROUP payload
-    # rows, tiled through the SAME index maps as the payload (vb//32 scale
-    # rows track each vb payload tile).  Zero-exponent padding dequantizes
-    # as identity.  Unscaled calls may run vb < SCALE_GROUP (kernel sweeps
-    # use tiny tiles); the never-read dummy then keeps one row per payload
-    # tile so no block dimension is zero.
-    if xscale is not None and vb % SCALE_GROUP:
-        raise ValueError(
-            f"xscale staging requires vb % {SCALE_GROUP} == 0, got vb={vb}")
-    sb = max(vb // SCALE_GROUP, 1)        # scale rows per payload tile
-    sc_rows = n_vb * sb
-    sc_dummy = jnp.zeros((sc_rows, 1), jnp.int8)
-    if xscale is not None:
+    # rows, laid out [n_vb, vb//32, Dx] so one block spans the minor two
+    # dimensions and follows the payload tile through the same index map.
+    # Zero-exponent padding dequantizes as identity.
+    have_scale = xscale is not None
+    if have_scale:
+        if vb % SCALE_GROUP:
+            raise ValueError(
+                f"xscale staging requires vb % {SCALE_GROUP} == 0, "
+                f"got vb={vb}")
+        sb = vb // SCALE_GROUP
         scp = jnp.pad(xscale.reshape(xscale.shape[0], -1),
-                      ((0, sc_rows - xscale.shape[0]),
+                      ((0, n_vb * sb - xscale.shape[0]),
                        (0, max(1 - xscale.shape[1], 0))))
-        ss_in, dss = (scp, dxs) if use_src else (sc_dummy, 1)
-        ds_in, dds = (scp, dxd) if use_dst else (sc_dummy, 1)
-    else:
-        ss_in, dss = sc_dummy, 1
-        ds_in, dds = sc_dummy, 1
+        scp = scp.reshape(n_vb, sb, scp.shape[1])
     evp = jnp.concatenate(
         [ev.reshape(e, -1), jnp.zeros((1, ev.shape[1]), ev.dtype)])
     if ev.shape[1] == 0:
@@ -409,47 +420,47 @@ def fused_triplet(
                    - (chunk_dst * vb)[:, None]).astype(jnp.int32)
     co = cs if to == "src" else cd
     clive = lp[perm].reshape(n_chunks, eb) & ~oob
-    cev = evp[perm].reshape(n_chunks, eb, de)
-    act = clive.any(axis=1)                       # chunk skip flag (dynamic)
-    clive_f = clive.astype(jnp.float32)
+    cedge = chunk_rows([cs, cd, co], clive)
+    cev = jnp.swapaxes(evp[perm].reshape(n_chunks, eb, de), 1, 2)
+    act = clive.any(axis=1).astype(jnp.int32)     # chunk skip flag (dynamic)
+
+    sq = pl.Squeezed()
+    in_specs = [
+        pl.BlockSpec((sq, 4, eb), lambda i, c, co_, cs_, cd_, a: (c, 0, 0)),
+        pl.BlockSpec((sq, de, eb), lambda i, c, co_, cs_, cd_, a: (c, 0, 0)),
+    ]
+    operands = [cedge, cev]
+    if use_src:
+        in_specs.append(pl.BlockSpec(
+            (vb, dx), lambda i, c, co_, cs_, cd_, a: (cs_[c], 0)))
+        operands.append(xp)
+    if use_dst:
+        in_specs.append(pl.BlockSpec(
+            (vb, dx), lambda i, c, co_, cs_, cd_, a: (cd_[c], 0)))
+        operands.append(xp)
+    if have_scale and use_src:
+        in_specs.append(pl.BlockSpec(
+            (sq, sb, dx), lambda i, c, co_, cs_, cd_, a: (cs_[c], 0, 0)))
+        operands.append(scp)
+    if have_scale and use_dst:
+        in_specs.append(pl.BlockSpec(
+            (sq, sb, dx), lambda i, c, co_, cs_, cd_, a: (cd_[c], 0, 0)))
+        operands.append(scp)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,                    # chunk_out/src/dst + act
         grid=(n_vb, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, eb), lambda i, c, co_, cs_, cd_, a: (c, 0)),
-            pl.BlockSpec((1, eb), lambda i, c, co_, cs_, cd_, a: (c, 0)),
-            pl.BlockSpec((1, eb), lambda i, c, co_, cs_, cd_, a: (c, 0)),
-            pl.BlockSpec((1, eb), lambda i, c, co_, cs_, cd_, a: (c, 0)),
-            pl.BlockSpec((1, eb, de), lambda i, c, co_, cs_, cd_, a: (c, 0, 0)),
-            pl.BlockSpec((vb, dxs), lambda i, c, co_, cs_, cd_, a: (cs_[c], 0)),
-            pl.BlockSpec((vb, dxd), lambda i, c, co_, cs_, cd_, a: (cd_[c], 0)),
-            pl.BlockSpec((sb, dss),
-                         lambda i, c, co_, cs_, cd_, a: (cs_[c], 0)),
-            pl.BlockSpec((sb, dds),
-                         lambda i, c, co_, cs_, cd_, a: (cd_[c], 0)),
-        ],
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((vb, dm), lambda i, c, co_, cs_, cd_, a: (i, 0)),
             pl.BlockSpec((vb, 1), lambda i, c, co_, cs_, cd_, a: (i, 0)),
         ],
     )
-
-    inner = _make_kernel(tile_fn, reduce, dm, xscale is not None)
-
-    def kern(co_ref, cs_ref, cd_ref, a_ref,
-             sloc_ref, dloc_ref, oloc_ref, live_ref, ev_ref,
-             xs_ref, xd_ref, ss_ref, ds_ref, out_ref, cnt_ref):
-        inner(co_ref, cs_ref, cd_ref, a_ref,
-              sloc_ref[0], dloc_ref[0], oloc_ref[0], live_ref[0], ev_ref[0],
-              xs_ref, xd_ref, ss_ref, ds_ref, out_ref, cnt_ref)
-
     out, cnt = pl.pallas_call(
-        kern,
+        _make_kernel(tile_fn, reduce, use_src, use_dst, have_scale),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((v_pad, dm), jnp.float32),
                    jax.ShapeDtypeStruct((v_pad, 1), jnp.float32)],
         interpret=interpret,
-    )(chunk_out, chunk_src, chunk_dst, act,
-      cs, cd, co, clive_f, cev, xs_in, xd_in, ss_in, ds_in)
+    )(chunk_out, chunk_src, chunk_dst, act, *operands)
     return out[:num_segments], cnt[:num_segments, 0]

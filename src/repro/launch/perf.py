@@ -1,9 +1,11 @@
 """Dry-run profiler for the perf hillclimb (§Perf methodology).
 
 Given a compiled cell, attribute collective bytes and HBM traffic to the
-JAX source operation (HLO metadata op_name), trip-count corrected — the
-"profile" the hypothesis->change->measure loop reads, since no real TPU
-wall-clock exists in this container.
+JAX source operation (HLO metadata op_name), trip-count corrected — a
+static profile read from the compiled program on simulated host devices.
+Nothing here runs on a chip: its times are a model built from the
+constants below, never a device measurement (chip_smoke.py runs on the
+chip).
 
   PYTHONPATH=src python -m repro.launch.perf --arch arctic-480b \
       --shape prefill_32k [--variant seqshard] [--top 15]

@@ -1,30 +1,21 @@
-"""jax-version compatibility shims for SPMD execution.
-
-One home for the two API seams that moved across jax releases, shared by the
-SPMD test lane (tests/spmd_check.py) and the benchmark harness
-(benchmarks/common.py) so the next API change is fixed in exactly one place.
-"""
+"""SPMD helpers shared by the SPMD test lane (tests/spmd_check.py), the
+benchmark harness (benchmarks/common.py) and chip_smoke.py's 4-chip phase,
+so the mesh and shard_map spelling lives in one place."""
 from __future__ import annotations
 
 import jax
 
 
 def make_mesh(shape, names, devices=None):
-    """jax.make_mesh across API generations (axis_types landed post-0.4)."""
+    """A mesh with explicit Auto axis types over `devices` (default: all)."""
     kw = {} if devices is None else {"devices": devices}
-    try:
-        return jax.make_mesh(shape, names,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(names), **kw)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, names, **kw)
+    return jax.make_mesh(shape, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+                         **kw)
 
 
 def shard_map(fn, mesh, in_specs, out_specs):
-    """jax.shard_map (check_vma) or jax.experimental's (check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    """jax.shard_map without the replication check: engine steps return
+    per-partition values the checker cannot prove replicated."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -1,0 +1,146 @@
+"""Ahead-of-time compiles of the main path's Pallas kernels for TPU v5e.
+
+Interpret mode runs any block layout; the chip's compiler refuses unaligned
+tiles, unsupported relayouts and VMEM overuse.  These tests compile each
+kernel of the Pregel main path for a described (not attached) v5e chip at
+its real block sizes, eb = vb = 512, with chunk and slot counts taken from a
+scale-16 Graph500 R-MAT build over 4 partitions.  Nothing runs; each compile
+takes a second or two.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and every test worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import segment_sum, superstep, triplet
+
+EB = VB = 512
+P = 4
+# scale-16 R-MAT (edge factor 16, seed 0), Graph.from_edges(num_partitions=4)
+E_BLK, V_MIR, V_BLK, K_DST = 249_264, 27_136, 11_752, 4_376
+TRIPLET_CHUNKS, APPLY_CHUNKS = 2_913, 92
+HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler to describe the chip with
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described chip cannot read back what it writes to the persistent
+    # cache, so keep the cache out of these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _specs(sharding):
+    """shape, dtype -> an argument of the described chip."""
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+
+
+def _tiles(spec, n_chunks):
+    return {"perm": spec((n_chunks * EB,), jnp.int32),
+            "chunk_out": spec((n_chunks,), jnp.int32),
+            "chunk_in": spec((n_chunks,), jnp.int32)}
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, total
+    return compiled
+
+
+def _pagerank_msg(sv, ev, dv):          # pr / deg * w, source side only
+    return sv[:, :1] / sv[:, 1:2] * ev[:, :1]
+
+
+def _cc_msg(sv, ev, dv):                # the source's label
+    return sv[:, :1]
+
+
+def _both_msg(sv, ev, dv):              # reads both endpoints
+    return (sv[:, :1] + dv[:, 1:2]) * ev[:, :1]
+
+
+@pytest.mark.parametrize("case", ["sum", "min", "sum_int8_xscale"])
+def test_fused_triplet_compiles_for_v5e(one_chip, case):
+    spec = _specs(one_chip)
+    seg = P * -(-V_MIR // VB) * VB
+    e = P * E_BLK
+    scaled = case.endswith("xscale")
+    x = spec((seg, 2), jnp.int8 if scaled else jnp.float32)
+    xscale = (spec((seg // triplet.SCALE_GROUP, 2), jnp.int8) if scaled
+              else None)
+    tile_fn = {"sum": _pagerank_msg, "min": _cc_msg,
+               "sum_int8_xscale": _both_msg}[case]
+    reduce = "min" if case == "min" else "sum"
+
+    def fn(x, ev, src, dst, live, tiles, xscale):
+        return triplet.fused_triplet(
+            x, ev, src, dst, live, tiles, tile_fn, seg, 1, xscale=xscale,
+            reduce=reduce, use_src=True, use_dst=scaled, eb=EB, vb=VB)
+
+    _compile(fn, x, spec((e, 1), jnp.float32), spec((e,), jnp.int32),
+             spec((e,), jnp.int32), spec((e,), jnp.bool_),
+             _tiles(spec, P * TRIPLET_CHUNKS), xscale)
+
+
+def _apply_fn(reduce):
+    def apply_fn(vid, vmask, xv, acc, exists):
+        msg = jnp.where(exists, acc, 0.0)
+        if reduce == "sum":
+            new = 0.15 + 0.85 * msg
+        else:
+            new = jnp.minimum(xv, jnp.where(exists, acc, xv))
+        new = jnp.where(vmask > 0.0, new, xv)
+        return new, jnp.logical_and(new != xv, vmask > 0.0).astype(
+            jnp.float32)
+    return apply_fn
+
+
+@pytest.mark.parametrize("reduce", ["sum", "min"])
+def test_fused_apply_compiles_for_v5e(one_chip, reduce):
+    spec = _specs(one_chip)
+    slots = P * -(-V_BLK // VB) * VB
+    r = P * P * K_DST
+    apply_fn = _apply_fn(reduce)
+
+    def fn(pay, slot, live, tiles, x, vid, vmask):
+        return superstep.fused_apply(pay, slot, live, tiles, x, vid, vmask,
+                                     apply_fn, slots, 1, 1, reduce=reduce,
+                                     eb=EB, vb=VB)
+
+    _compile(fn, spec((r, 1), jnp.float32), spec((r,), jnp.int32),
+             spec((r,), jnp.bool_), _tiles(spec, P * APPLY_CHUNKS),
+             spec((slots, 1), jnp.float32), spec((slots,), jnp.int32),
+             spec((slots,), jnp.bool_))
+
+
+def test_segment_sum_compiles_for_v5e(one_chip):
+    spec = _specs(one_chip)
+    e = P * E_BLK
+    num_segments = P * V_MIR
+
+    def fn(msgs, ids):
+        return segment_sum.segment_sum(msgs, ids, num_segments)
+
+    _compile(fn, spec((e, 1), jnp.float32), spec((e,), jnp.int32))

@@ -436,9 +436,7 @@ def test_ship_metrics_zero_matches_live_dtypes_under_x64():
     which is int64 under the x64 config.  A statically-clean refresh and a
     shipping refresh then presented different avals across lax.cond
     branches.  zero() must track the config."""
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         flags = jnp.zeros((2, 4), bool)
         live = ShipMetrics(wire_bytes=0,
                            effective_bytes=flags.sum() * 4,
