@@ -13,6 +13,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import trace
 from .graph import Graph
 from .pregel import pregel, pregel_fused, PregelResult
 from .tree import vmap2
@@ -35,19 +36,27 @@ def attach_out_degree(g: Graph, kernel_mode: str = "auto") -> Graph:
     subgraph restriction), so its mirror must go dirty, not stay clean."""
     from . import view as view_mod
     from .graph import _degree_msg
-    # the method call (not bare degrees()) keeps the graph lineage: the
-    # degree aggregation's wire traffic lands in the pipeline wire log
-    vals, exists, g, _ = g.mrTriplets(_degree_msg, "sum", to="src",
-                                      kernel_mode=kernel_mode)
-    deg = jnp.where(exists, vals["deg"], 0.0)
-    old = g.vdata if isinstance(g.vdata, dict) else {"v": g.vdata}
-    vdata = {**old, "deg": jnp.maximum(deg, 1.0)}
-    view = view_mod.view_after_rewrite(
-        g.view, old, vdata, view_mod.keep_through(old, exclude=("deg",)),
-        None)
-    return g.replace(vdata=vdata, view=view)
+    with trace.span("graphx.operator", op="mrTriplets"):
+        # the method call (not bare degrees()) keeps the graph lineage: the
+        # degree aggregation's wire traffic lands in the pipeline wire log
+        vals, exists, g, _ = g.mrTriplets(_degree_msg, "sum", to="src",
+                                          kernel_mode=kernel_mode)
+        deg = jnp.where(exists, vals["deg"], 0.0)
+        old = g.vdata if isinstance(g.vdata, dict) else {"v": g.vdata}
+        vdata = {**old, "deg": jnp.maximum(deg, 1.0)}
+        view = view_mod.view_after_rewrite(
+            g.view, old, vdata, view_mod.keep_through(old, exclude=("deg",)),
+            None)
+        return g.replace(vdata=vdata, view=view)
 
 
+def _map_vertices(g: Graph, fn: Callable) -> Graph:
+    """`g.mapV(fn)` under a `graphx.operator` span."""
+    with trace.span("graphx.operator", op="mapV"):
+        return g.mapV(fn)
+
+
+@trace.algorithm
 def pagerank(g: Graph, *, num_iters: int = 20, reset: float = 0.15,
              tol: float = 0.0, kernel_mode: str = "auto",
              incremental: bool = True, track_metrics: bool = False,
@@ -68,7 +77,7 @@ def pagerank(g: Graph, *, num_iters: int = 20, reset: float = 0.15,
     g = attach_out_degree(g, kernel_mode)
 
     if tol <= 0.0:
-        g = g.mapV(lambda vid, v: {**v, "pr": jnp.float32(1.0)})
+        g = _map_vertices(g, lambda vid, v: {**v, "pr": jnp.float32(1.0)})
 
         def send(sv, ev, dv):
             return {"m": sv["pr"] / sv["deg"] * ev["w"]}
@@ -83,8 +92,8 @@ def pagerank(g: Graph, *, num_iters: int = 20, reset: float = 0.15,
             track_metrics=track_metrics, transport=transport)
 
     # delta formulation: pr0 = reset, delta0 = reset
-    g = g.mapV(lambda vid, v: {**v, "pr": jnp.float32(reset),
-                               "delta": jnp.float32(reset)})
+    g = _map_vertices(g, lambda vid, v: {**v, "pr": jnp.float32(reset),
+                                         "delta": jnp.float32(reset)})
 
     def send(sv, ev, dv):
         return {"m": sv["delta"] / sv["deg"] * ev["w"]}
@@ -119,6 +128,7 @@ def pagerank_reference(src: np.ndarray, dst: np.ndarray, n: int,
 # --------------------------------------------------------------------------
 # Connected components (paper Listing 6; evaluation §5.1)
 # --------------------------------------------------------------------------
+@trace.algorithm
 def connected_components(g: Graph, *, max_supersteps: int = 100,
                          kernel_mode: str = "auto", incremental: bool = True,
                          track_metrics: bool = False,
@@ -133,7 +143,7 @@ def connected_components(g: Graph, *, max_supersteps: int = 100,
     with both (u,v) and (v,u) edges (data/graphs.py does this), matching how
     Giraph/GraphLab benchmark CC.
     """
-    g = g.mapV(lambda vid, v: {"cc": vid})
+    g = _map_vertices(g, lambda vid, v: {"cc": vid})
 
     def send(sv, ev, dv):
         return {"m": sv["cc"]}
@@ -168,9 +178,10 @@ def connected_components_reference(src, dst, vids) -> dict[int, int]:
 # --------------------------------------------------------------------------
 # Single-source shortest paths
 # --------------------------------------------------------------------------
+@trace.algorithm
 def sssp(g: Graph, source: int, *, max_supersteps: int = 100,
          kernel_mode: str = "auto") -> PregelResult:
-    g = g.mapV(lambda vid, v: {
+    g = _map_vertices(g, lambda vid, v: {
         "dist": jnp.where(vid == source, jnp.float32(0.0), INF32)})
 
     def send(sv, ev, dv):
@@ -187,6 +198,7 @@ def sssp(g: Graph, source: int, *, max_supersteps: int = 100,
 # --------------------------------------------------------------------------
 # Label propagation (K-label voting — associative formulation)
 # --------------------------------------------------------------------------
+@trace.algorithm
 def label_propagation(g: Graph, num_labels: int, *, num_iters: int = 10,
                       kernel_mode: str = "auto") -> PregelResult:
     """Each vertex adopts the argmax of neighbour label votes.  Votes are
@@ -212,6 +224,7 @@ def label_propagation(g: Graph, num_labels: int, *, num_iters: int = 10,
 # Triangle counting — a genuinely 3-way-join workload (contrast with
 # PageRank's join-eliminated 2-way; benchmark fodder for Fig. 5)
 # --------------------------------------------------------------------------
+@trace.algorithm
 def triangle_count(g: Graph, *, n_ids: int | None = None,
                    kernel_mode: str = "auto"):
     """Triangles via the narrow waist, two mrTriplets passes.
@@ -229,7 +242,7 @@ def triangle_count(g: Graph, *, n_ids: int | None = None,
     n_ids = n_ids or g.s.num_vertices
     w = (n_ids + 31) // 32
 
-    g1 = g.mapV(lambda vid, v: {"vid": vid})
+    g1 = _map_vertices(g, lambda vid, v: {"vid": vid})
 
     def send_bits(sv, ev, dv):
         word = (sv["vid"] // 32).astype(jnp.int32)
@@ -237,8 +250,9 @@ def triangle_count(g: Graph, *, n_ids: int | None = None,
                              (sv["vid"] % 32).astype(jnp.uint32))
         return {"bits": jnp.zeros((w,), jnp.uint32).at[word].set(bit)}
 
-    bits, exists, _, m1 = g1.mrTriplets(send_bits, "sum", to="dst",
-                                        kernel_mode=kernel_mode)
+    with trace.span("graphx.operator", op="mrTriplets"):
+        bits, exists, _, m1 = g1.mrTriplets(send_bits, "sum", to="dst",
+                                            kernel_mode=kernel_mode)
     nbr = jnp.where(exists[..., None], bits["bits"], jnp.uint32(0))
     g2 = g1.replace(vdata={"bits": nbr})
 
@@ -247,8 +261,9 @@ def triangle_count(g: Graph, *, n_ids: int | None = None,
         cnt = jax.lax.population_count(inter).sum().astype(jnp.float32)
         return {"c": cnt}
 
-    cnts, exists2, _, m2 = g2.mrTriplets(send_common, "sum", to="dst",
-                                         kernel_mode=kernel_mode)
+    with trace.span("graphx.operator", op="mrTriplets"):
+        cnts, exists2, _, m2 = g2.mrTriplets(send_common, "sum", to="dst",
+                                             kernel_mode=kernel_mode)
     per_vertex = jnp.where(exists2, cnts["c"], 0.0) / 2.0
     total = per_vertex.sum() / 3.0
     return per_vertex, total, {"phase1": m1, "phase2": m2}
@@ -273,6 +288,7 @@ def triangle_count_reference(src, dst, n: int) -> int:
 # --------------------------------------------------------------------------
 # Coarsen (paper Listing 7) — the unified data-/graph-parallel pipeline
 # --------------------------------------------------------------------------
+@trace.algorithm
 def coarsen(g: Graph, epred: Callable, merge: str = "sum",
             *, kernel_mode: str = "auto") -> Graph:
     """Collapse edges satisfying `epred`; vertices in the same contracted

@@ -207,9 +207,10 @@ def _route_ship(ex: Exchange, sendbuf: Any, flags: jnp.ndarray, *,
     codec = ex.codec
     transport_mod.record_ship(label, transport.kind,
                               f"K={flags.shape[-1]}")
-    recvbuf, rflags, info = transport_mod.ship_transport(
-        ex, sendbuf, flags, bound=bound, policy=transport,
-        prefer_ragged=prefer_ragged, recvflags=recvflags)
+    with jax.named_scope("graphx.exchange"):
+        recvbuf, rflags, info = transport_mod.ship_transport(
+            ex, sendbuf, flags, bound=bound, policy=transport,
+            prefer_ragged=prefer_ragged, recvflags=recvflags)
     metrics = ShipMetrics(
         wire_bytes=wire_mod.static_wire_bytes(sendbuf, codec, bound),
         effective_bytes=flags.sum() * elem_bytes,
@@ -762,7 +763,10 @@ def _fused_aggregate(g, mirror_tree, map_fn, live, to, reduce, kernel_mode,
     `mirror_tree` may hold narrow-RESIDENT leaves (§2.4): when every used
     leaf shares one encoded layout the kernel streams the NARROW payload
     plus its scale plane and dequantizes per tile in VMEM; otherwise the
-    tree decodes on read here — ineligible mixes never error."""
+    tree decodes on read here — ineligible mixes never error.
+
+    Returns (partial, had_msg, chunks_live): the kernel's count of chunks
+    with a live edge, None on the jnp oracle."""
     s = g.s
     nl = live.shape[0]
     vb = FUSED_VERTEX_BLOCK
@@ -795,7 +799,7 @@ def _fused_aggregate(g, mirror_tree, map_fn, live, to, reduce, kernel_mode,
                             tuple(jax.tree.leaves(vex)), jax.tree.structure(vex),
                             tuple(jax.tree.leaves(eex)), jax.tree.structure(eex),
                             plan)
-    out, cnt = kops.triplet(
+    out, cnt, chunks_live = kops.triplet(
         x, ev, fsrc, fdst, live.reshape(-1), tiles, tile_fn, seg, plan.dm,
         xscale=xscale, to=to, reduce=reduce, use_src=any(plan.src_used),
         use_dst=any(plan.dst_used), mode=kernel_mode,
@@ -819,7 +823,7 @@ def _fused_aggregate(g, mirror_tree, map_fn, live, to, reduce, kernel_mode,
                              _REDUCE_IDENTITY[reduce](dtype))
         leaves.append(leaf)
     partial = jax.tree.unflatten(plan.msg_treedef, leaves)
-    return partial, had_msg
+    return partial, had_msg, chunks_live
 
 
 def mr_triplets(
@@ -862,7 +866,9 @@ def mr_triplets(
     attach it (`g.replace(view=...)`, or use the `Graph.mrTriplets` method
     which does) and the next consumer ships only dirty leaves / missing
     directions; `metrics["ships_fwd"]` is the STATIC number of forward
-    route collectives this call emitted (0 on a clean view).
+    route collectives this call emitted (0 on a clean view);
+    `metrics["live_edges"]` counts the edges that send a message and, on
+    the fused kernel, `metrics["chunks_live"]` the chunks it swept.
 
     cache: explicit view override restoring the legacy §4.5.1 loop
     contract — the supplied view plus `g.active` as the changed-row set
@@ -996,10 +1002,12 @@ def mr_triplets(
     vis_mir = None
     if need is not None or with_vis:
         lm = leaf_mask if need is not None else (False,) * len(flat_vals)
-        view, mirror_tree, vis_mir, m_fwd, ships_fwd = view_mod.refresh_view(
-            g, need or "both", leaf_mask=lm, with_vis=with_vis, bound=bound,
-            transport=tp, prefer_ragged=prefer_ragged,
-            legacy_cache=cache if legacy else None)
+        with jax.named_scope("graphx.view"):
+            refreshed = view_mod.refresh_view(
+                g, need or "both", leaf_mask=lm, with_vis=with_vis,
+                bound=bound, transport=tp, prefer_ragged=prefer_ragged,
+                legacy_cache=cache if legacy else None)
+        view, mirror_tree, vis_mir, m_fwd, ships_fwd = refreshed
         metrics["fwd"] = m_fwd
         if need is None:
             # no vertex PROPERTY was read: this call carries no property
@@ -1071,9 +1079,13 @@ def mr_triplets(
         # the kernel dequantizes per tile in VMEM.  The decoded mirror_tree
         # stays the source for epred / the unfused gather above.
         enc_tree = view.mirror if view is not None else mirror_tree
-        partial, had_msg = _fused_aggregate(
+        partial, had_msg, chunks_live = _fused_aggregate(
             g, enc_tree, map_fn, live, to, reduce, kernel_mode, plan,
             vex, eex)
+        if chunks_live is not None:
+            # the §4.6 index scan at grid granularity: chunks the kernel
+            # swept, out of its static (vertex block × chunk) grid
+            metrics["chunks_live"] = chunks_live
     else:
         zeros_elem = tree_zeros_like_elem(g.vdata, (nl, s.e_blk))
         svals = gather_rows(mirror_tree, s.src_slot) if uses_src else zeros_elem
@@ -1400,6 +1412,16 @@ def apply_plan_of(g, vprog: Callable, send_msg: Callable,
     plan = _plan_apply(g, vprog, send_msg, reduce, changed_fn, default_msg,
                        payload_bound)
     return "fused_apply" if plan is not None else "unfused"
+
+
+def sweep_grid(s, to: str = "dst") -> tuple[int, int]:
+    """(chunks, grid steps) of one fused triplet sweep toward `to` over all
+    partitions of structure `s`, from its tile tables' shapes: the kernel's
+    grid is every (vertex block, chunk) pair of the flat space that
+    `_fused_aggregate` builds, and a live chunk works in one of them."""
+    p, n_chunks = s.tiles[to]["chunk_out"].shape
+    n_vb = max(-(-s.v_mir // FUSED_VERTEX_BLOCK), 1)
+    return p * n_chunks, p * n_vb * p * n_chunks
 
 
 def plan_of(g, map_fn: Callable, reduce: str = "sum", *,

@@ -41,6 +41,7 @@ import numpy as np
 _log = logging.getLogger(__name__)
 
 from . import analysis
+from . import trace
 from . import transport as transport_mod
 from . import view as view_mod
 from .graph import Graph
@@ -93,30 +94,32 @@ def _superstep(g: Graph, tstate=None, *, vprog, send_msg, gather,
     # re-derivable from the UDF analysis in the driver
     metrics = {k: v for k, v in metrics.items()
                if not isinstance(v, (str, int))}
-    if aplan is not None:
-        # fused §2.3.2 path: `msgs` here is the RAW routed aggregate tree
-        # (per-source-partition partials, not yet combined) — the kernel
-        # combines them and runs vprog + changed derivation in one sweep,
-        # so the combined messages / defaulted messages / changed mask
-        # never materialise to HBM on the home side.
-        new_vdata, changed = fused_apply_home(
-            g, msgs, exists, "dst", gather, aplan, vprog, changed_fn,
-            kernel_mode)
-        msg_elem = jax.tree.unflatten(aplan.msg_treedef,
-                                      list(aplan.msg_specs))
-    else:
-        msgs_or_default = tree_where(exists, msgs, jax.tree.map(
-            lambda d, m: jnp.broadcast_to(jnp.asarray(d, m.dtype), m.shape),
-            default_msg, msgs))
-        new_vdata = vmap2(vprog)(g.s.home_vid, g.vdata, msgs_or_default)
-        new_vdata = tree_where(g.vmask, new_vdata, g.vdata)
-        if changed_fn is None:
-            changed = tree_changed(new_vdata, g.vdata)
+    with jax.named_scope("graphx.apply"):
+        if aplan is not None:
+            # fused §2.3.2 path: `msgs` here is the RAW routed aggregate tree
+            # (per-source-partition partials, not yet combined) — the kernel
+            # combines them and runs vprog + changed derivation in one sweep,
+            # so the combined messages / defaulted messages / changed mask
+            # never materialise to HBM on the home side.
+            new_vdata, changed = fused_apply_home(
+                g, msgs, exists, "dst", gather, aplan, vprog, changed_fn,
+                kernel_mode)
+            msg_elem = jax.tree.unflatten(aplan.msg_treedef,
+                                          list(aplan.msg_specs))
         else:
-            changed = vmap2(changed_fn)(g.vdata, new_vdata)
-        changed = changed & g.vmask
-        msg_elem = elem_spec(msgs_or_default)
-    live = changed.sum()
+            msgs_or_default = tree_where(exists, msgs, jax.tree.map(
+                lambda d, m: jnp.broadcast_to(jnp.asarray(d, m.dtype),
+                                              m.shape),
+                default_msg, msgs))
+            new_vdata = vmap2(vprog)(g.s.home_vid, g.vdata, msgs_or_default)
+            new_vdata = tree_where(g.vmask, new_vdata, g.vdata)
+            if changed_fn is None:
+                changed = tree_changed(new_vdata, g.vdata)
+            else:
+                changed = vmap2(changed_fn)(g.vdata, new_vdata)
+            changed = changed & g.vmask
+            msg_elem = elem_spec(msgs_or_default)
+        live = changed.sum()
     if use_cache:
         # per-leaf dirty feed: leaves vprog provably passes through (jaxpr
         # analysis — delta PageRank's `deg`) stay CLEAN and never re-ship;
@@ -127,8 +130,9 @@ def _superstep(g: Graph, tstate=None, *, vprog, send_msg, gather,
         rewrites = analysis.analyze_rewrites(
             vprog, (jax.ShapeDtypeStruct((), g.s.home_vid.dtype),
                     elem_spec(g.vdata), msg_elem), 1)
-        view = view_mod.view_after_rewrite(
-            view, g.vdata, new_vdata, rewrites, changed)
+        with jax.named_scope("graphx.view"):
+            view = view_mod.view_after_rewrite(
+                view, g.vdata, new_vdata, rewrites, changed)
     log = g.wire_log
     if log is not None:
         m = metrics["fwd"].merge(metrics["back"])
@@ -203,163 +207,233 @@ def pregel(
     static metadata, so each tier compiles once and shipped bytes shrink
     with the active set (the runtime lax.cond overflow fallback still
     guards every ragged step).  The per-superstep metrics record the
-    decision next to `plan` ("transport", "transport_cap", "ragged")."""
+    decision next to `plan` ("transport", "transport_cap", "ragged").
 
-    step = jax.jit(functools.partial(
-        _superstep, vprog=vprog, send_msg=send_msg, gather=gather,
-        default_msg=default_msg, skip_stale=skip_stale,
-        changed_fn=changed_fn, kernel_mode=kernel_mode,
-        use_cache=incremental, payload_bound=payload_bound,
-        fuse_apply=fuse_apply),
-        static_argnames=("transport",))
+    Spans (core/trace.py): `graphx.pregel` over the call (`supersteps` set
+    at exit), and in it `graphx.pregel.plan` before the loop, then per
+    superstep `graphx.pregel.dispatch` (`first=1` where the call traced a
+    program), `graphx.pregel.sync` (the host's reads of the step's
+    results) and, where they run, `graphx.pregel.spill`, `.record`,
+    `.adapt` and `.checkpoint`.  Only while a profiler trace runs does the
+    sync span also read the fused triplet sweep's `chunks_live` and carry
+    it beside the grid's static `chunks` and `grid_steps`."""
 
-    # static join-elimination + physical-plan facts, derived once from the
-    # INITIAL graph's specs (vprog may retype properties, but every §3.3
-    # algorithm keeps the message shape fixed across supersteps)
-    from .mrtriplets import _derive_need, plan_of
-    deps = analysis.analyze_message_fn(
-        send_msg, elem_spec(g.vdata), elem_spec(g.edata), elem_spec(g.vdata))
-    tp = transport_mod.resolve_transport(transport)
-    fuse = (kernel_mode != "unfused"
-            and fuse_apply not in (False, "unfused"))
-    static_info = {"join_arity": deps.n_way,
-                   "need": _derive_need(deps, None) or "none",
-                   "wire": (g.ex.codec.name if g.ex.codec is not None
-                            else "f32"),
-                   "transport_policy": tp.kind,
-                   "plan": plan_of(g, send_msg, gather,
-                                   kernel_mode=kernel_mode,
-                                   payload_bound=payload_bound),
-                   "apply_plan": (apply_plan_of(
-                       g, vprog, send_msg, gather, changed_fn=changed_fn,
-                       default_msg=default_msg, kernel_mode=kernel_mode,
-                       payload_bound=payload_bound) if fuse else "unfused")}
+    with trace.span("graphx.pregel") as whole:
+        with trace.span("graphx.pregel.plan"):
+            step = superstep_jit(
+                vprog, send_msg, gather, default_msg=default_msg,
+                skip_stale=skip_stale, changed_fn=changed_fn,
+                kernel_mode=kernel_mode, incremental=incremental,
+                payload_bound=payload_bound, fuse_apply=fuse_apply)
 
-    # host-side transport re-planning ("auto"): superstep 0 is a full ship
-    # (dense by construction), later plans come from adapt_policy on the
-    # observed active fraction + route occupancy of the step just run.
-    cur_tp = transport_mod.DENSE if tp.kind == "auto" else tp
+            # static join-elimination + physical-plan facts, derived once
+            # from the INITIAL graph's specs (vprog may retype properties,
+            # but every §3.3 algorithm keeps the message shape fixed across
+            # supersteps)
+            from .mrtriplets import _derive_need, plan_of, sweep_grid
+            deps = analysis.analyze_message_fn(
+                send_msg, elem_spec(g.vdata), elem_spec(g.edata),
+                elem_spec(g.vdata))
+            tp = transport_mod.resolve_transport(transport)
+            fuse = (kernel_mode != "unfused"
+                    and fuse_apply not in (False, "unfused"))
+            static_info = {
+                "join_arity": deps.n_way,
+                "need": _derive_need(deps, None) or "none",
+                "wire": (g.ex.codec.name if g.ex.codec is not None
+                         else "f32"),
+                "transport_policy": tp.kind,
+                "plan": plan_of(g, send_msg, gather,
+                                kernel_mode=kernel_mode,
+                                payload_bound=payload_bound),
+                "apply_plan": (apply_plan_of(
+                    g, vprog, send_msg, gather, changed_fn=changed_fn,
+                    default_msg=default_msg, kernel_mode=kernel_mode,
+                    payload_bound=payload_bound) if fuse else "unfused")}
+            # the fused triplet sweep's static grid, for the sync spans
+            grid = (sweep_grid(g.s) if static_info["plan"] == "fused"
+                    else None)
 
-    # §6 superstep checkpointing: resolve the store and, on resume, swap in
-    # the snapshotted carry BEFORE deriving anything from the graph.
-    store = None
-    start = 0
-    if checkpoint is not None:
-        from . import snapshot as snapshot_mod
-        store = (checkpoint
-                 if isinstance(checkpoint, snapshot_mod.SnapshotStore)
-                 else snapshot_mod.SnapshotStore(checkpoint))
-        if resume and store.latest_step() is not None:
-            g, start, saved_tp, _live = snapshot_mod.restore_pregel(store, g)
-            if saved_tp is not None:
-                # the snapshot stores the POST-adapt policy: the next
-                # superstep runs exactly the plan the killed run chose.
-                cur_tp = saved_tp
+            # host-side transport re-planning ("auto"): superstep 0 is a
+            # full ship (dense by construction), later plans come from
+            # adapt_policy on the observed active fraction + route
+            # occupancy of the step just run.
+            cur_tp = transport_mod.DENSE if tp.kind == "auto" else tp
 
-    # §2.4 out-of-core residency: the ring lives entirely in the host loop
-    # (the jitted step never traces through it) — restore before, spill
-    # after every superstep.
-    ring = None
-    if working_set_frac is not None and working_set_frac < 1.0:
-        from . import spill as spill_mod
-        ring = spill_mod.SpillRing(plan=spill_mod.plan_spill(
-            g, working_set_frac))
+            # §6 superstep checkpointing: resolve the store and, on resume,
+            # swap in the snapshotted carry BEFORE deriving anything from
+            # the graph.
+            store = None
+            start = 0
+            if checkpoint is not None:
+                from . import snapshot as snapshot_mod
+                store = (checkpoint
+                         if isinstance(checkpoint, snapshot_mod.SnapshotStore)
+                         else snapshot_mod.SnapshotStore(checkpoint))
+                if resume and store.latest_step() is not None:
+                    g, start, saved_tp, _live = snapshot_mod.restore_pregel(
+                        store, g)
+                    if saved_tp is not None:
+                        # the snapshot stores the POST-adapt policy: the
+                        # next superstep runs exactly the plan the killed
+                        # run chose.
+                        cur_tp = saved_tp
 
-    n_visible = max(int(jnp.sum(g.vmask)), 1)
-    # each DISTINCT static transport plan the jitted step has seen is one
-    # XLA compile — the hysteresis in adapt_policy (prev=) exists to keep
-    # this set small on oscillating frontiers.
-    plans_seen = {cur_tp}
+            # §2.4 out-of-core residency: the ring lives entirely in the
+            # host loop (the jitted step never traces through it) — restore
+            # before, spill after every superstep.
+            ring = None
+            if working_set_frac is not None and working_set_frac < 1.0:
+                from . import spill as spill_mod
+                ring = spill_mod.SpillRing(plan=spill_mod.plan_spill(
+                    g, working_set_frac))
 
-    all_metrics: list[dict] = []
-    steps = 0
-    for it in range(start, max_supersteps):
-        if ring is not None:
-            g = ring.restore(g)    # prefetch ring drained: fully resident
-        g, live, metrics = step(g, transport=cur_tp)
-        steps += 1
-        if ring is not None:
-            g = ring.spill(g)      # cold cells to host; carry slims
-        fwd, back = metrics["fwd"], metrics["back"]
-        # §6 graceful-degradation accounting, surfaced every superstep:
-        # overflow = ragged plan fell back to a dense ship (bytes worse,
-        # values exact), wire_faults/degraded = integrity-word failures
-        # retried / degraded to raw f32 for the step.
-        overflow_fallbacks = float(fwd.overflow + back.overflow)
-        wire_faults = float(fwd.wire_faults + back.wire_faults)
-        degraded_routes = float(fwd.degraded + back.degraded)
-        if overflow_fallbacks:
-            _log.warning(
-                "pregel superstep %d: ragged transport overflowed its "
-                "static capacity %d time(s); shipped dense this step "
-                "(values exact, bytes worse)", it, int(overflow_fallbacks))
-        if track_metrics:
-            # scalars -> float; [P] vectors (per-destination occupancy,
-            # §2.1.3) -> plain lists so the dict stays JSON-able.
-            host_metrics = jax.tree.map(
-                lambda x: float(x) if jnp.ndim(x) == 0
-                else np.asarray(x).tolist(), metrics)
-            host_metrics.update(static_info)
-            host_metrics["transport"] = cur_tp.kind
-            host_metrics["transport_cap"] = cur_tp.cap or 0
-            host_metrics["transport_frac"] = (
-                cur_tp.capacity_frac if cur_tp.kind == "ragged" else 0.0)
-            host_metrics["recompiles"] = len(plans_seen)
-            host_metrics["overflow_fallbacks"] = overflow_fallbacks
-            host_metrics["wire_faults"] = wire_faults
-            host_metrics["degraded_routes"] = degraded_routes
-            # pipeline-level accumulation (§3.1): the graph's wire log
-            # counts this loop's traffic on top of whatever the operator
-            # chain BEFORE it already shipped.
-            host_metrics["pipeline_ships"] = float(g.ships)
-            host_metrics["pipeline_bytes_shipped"] = float(g.bytes_shipped)
+            n_visible = max(int(jnp.sum(g.vmask)), 1)
+        # each DISTINCT static transport plan the jitted step has seen is
+        # one XLA compile — the hysteresis in adapt_policy (prev=) exists to
+        # keep this set small on oscillating frontiers.
+        plans_seen = {cur_tp}
+
+        all_metrics: list[dict] = []
+        steps = 0
+        for it in range(start, max_supersteps):
             if ring is not None:
-                # §2.4 modeled streaming trajectory: the rotation just run
-                # (this step's spill + the restore that preceded it).
-                host_metrics.update(ring.stream_times(g))
-                host_metrics["spill_resident_bytes"] = float(
-                    ring.resident_bytes(g))
-                host_metrics["spill_host_bytes"] = float(ring.host_bytes())
-            all_metrics.append(host_metrics)
-        if int(live) == 0:
-            break
-        if tp.kind == "auto":
-            def _occ(m):
-                # per-DESTINATION occupancy vector when the transport
-                # surfaced one (§2.1.3 tier planning); scalar worst-route
-                # fraction otherwise.
-                v = np.asarray(m.route_active_frac)
-                if v.ndim == 1 and v.size > 1:
-                    return tuple(float(x) for x in v)
-                return int(m.route_active_max) / max(m.route_width, 1)
-            cur_tp = transport_mod.adapt_policy(
-                tp, was_ragged=cur_tp.kind == "ragged",
-                active_frac=float(live) / n_visible,
-                fwd_frac=_occ(fwd),
-                back_frac=_occ(back),
-                prev=cur_tp)
-            plans_seen.add(cur_tp)
-        if store is not None:
-            # checkpoint AFTER adapt so the saved policy is the one the
-            # next superstep would run; a preemption request (SIGTERM via
-            # train.fault.PreemptionGuard) forces a snapshot at this
-            # boundary and exits the loop.
-            preempt = guard is not None and getattr(guard, "requested",
-                                                    False)
-            due = (checkpoint_every is not None
-                   and (it + 1 - start) % checkpoint_every == 0)
-            if due or preempt:
-                # snapshot the FULL graph: peek() merges the host store
-                # without draining the ring (§2.4 snapshot compatibility).
-                snapshot_mod.save_pregel(
-                    store, it + 1, ring.peek(g) if ring is not None else g,
-                    cur_tp, live=int(live))
-                if preempt:
-                    break
-    if ring is not None:
-        g = ring.materialize(g)    # exit fully resident, like the carry in
+                with trace.span("graphx.pregel.spill"):
+                    g = ring.restore(g)    # prefetch ring drained: resident
+            with trace.span("graphx.pregel.dispatch") as dispatch:
+                programs = step._cache_size()
+                g, live, metrics = step(g, transport=cur_tp)
+                # first=1: this dispatch traced, lowered and compiled (or
+                # loaded) a program — a plan's first, or the first after
+                # the view's static state changed (the cold first ship)
+                dispatch.set_metadata(
+                    first=int(step._cache_size() > programs))
+            steps += 1
+            if ring is not None:
+                with trace.span("graphx.pregel.spill"):
+                    g = ring.spill(g)      # cold cells to host; carry slims
+            fwd, back = metrics["fwd"], metrics["back"]
+            with trace.span("graphx.pregel.sync") as sync:
+                n_live = int(live)
+                # §6 graceful-degradation accounting, surfaced every
+                # superstep: overflow = ragged plan fell back to a dense
+                # ship (bytes worse, values exact), wire_faults/degraded =
+                # integrity-word failures retried / degraded to raw f32 for
+                # the step.
+                overflow_fallbacks = float(fwd.overflow + back.overflow)
+                wire_faults = float(fwd.wire_faults + back.wire_faults)
+                degraded_routes = float(fwd.degraded + back.degraded)
+                # the grid counter is read only into a running trace: the
+                # device has finished the step, so this adds one transfer
+                if (grid is not None and "chunks_live" in metrics
+                        and trace.active()):
+                    sync.set_metadata(
+                        chunks_live=int(metrics["chunks_live"]),
+                        chunks=grid[0], grid_steps=grid[1])
+            if overflow_fallbacks:
+                _log.warning(
+                    "pregel superstep %d: ragged transport overflowed its "
+                    "static capacity %d time(s); shipped dense this step "
+                    "(values exact, bytes worse)", it,
+                    int(overflow_fallbacks))
+            if track_metrics:
+                with trace.span("graphx.pregel.record"):
+                    # scalars -> float; [P] vectors (per-destination
+                    # occupancy, §2.1.3) -> plain lists so the dict stays
+                    # JSON-able.
+                    host_metrics = jax.tree.map(
+                        lambda x: float(x) if jnp.ndim(x) == 0
+                        else np.asarray(x).tolist(), metrics)
+                    host_metrics.update(static_info)
+                    host_metrics["transport"] = cur_tp.kind
+                    host_metrics["transport_cap"] = cur_tp.cap or 0
+                    host_metrics["transport_frac"] = (
+                        cur_tp.capacity_frac if cur_tp.kind == "ragged"
+                        else 0.0)
+                    host_metrics["recompiles"] = len(plans_seen)
+                    host_metrics["overflow_fallbacks"] = overflow_fallbacks
+                    host_metrics["wire_faults"] = wire_faults
+                    host_metrics["degraded_routes"] = degraded_routes
+                    # pipeline-level accumulation (§3.1): the graph's wire
+                    # log counts this loop's traffic on top of whatever the
+                    # operator chain BEFORE it already shipped.
+                    host_metrics["pipeline_ships"] = float(g.ships)
+                    host_metrics["pipeline_bytes_shipped"] = float(
+                        g.bytes_shipped)
+                    if ring is not None:
+                        # §2.4 modeled streaming trajectory: the rotation
+                        # just run (this step's spill + the restore that
+                        # preceded it).
+                        host_metrics.update(ring.stream_times(g))
+                        host_metrics["spill_resident_bytes"] = float(
+                            ring.resident_bytes(g))
+                        host_metrics["spill_host_bytes"] = float(
+                            ring.host_bytes())
+                    all_metrics.append(host_metrics)
+            if n_live == 0:
+                break
+            if tp.kind == "auto":
+                with trace.span("graphx.pregel.adapt"):
+                    def _occ(m):
+                        # per-DESTINATION occupancy vector when the
+                        # transport surfaced one (§2.1.3 tier planning);
+                        # scalar worst-route fraction otherwise.
+                        v = np.asarray(m.route_active_frac)
+                        if v.ndim == 1 and v.size > 1:
+                            return tuple(float(x) for x in v)
+                        return (int(m.route_active_max)
+                                / max(m.route_width, 1))
+                    cur_tp = transport_mod.adapt_policy(
+                        tp, was_ragged=cur_tp.kind == "ragged",
+                        active_frac=n_live / n_visible,
+                        fwd_frac=_occ(fwd),
+                        back_frac=_occ(back),
+                        prev=cur_tp)
+                plans_seen.add(cur_tp)
+            if store is not None:
+                # checkpoint AFTER adapt so the saved policy is the one the
+                # next superstep would run; a preemption request (SIGTERM
+                # via train.fault.PreemptionGuard) forces a snapshot at this
+                # boundary and exits the loop.
+                preempt = guard is not None and getattr(guard, "requested",
+                                                        False)
+                due = (checkpoint_every is not None
+                       and (it + 1 - start) % checkpoint_every == 0)
+                if due or preempt:
+                    # snapshot the FULL graph: peek() merges the host store
+                    # without draining the ring (§2.4 snapshot
+                    # compatibility).
+                    with trace.span("graphx.pregel.checkpoint"):
+                        snapshot_mod.save_pregel(
+                            store, it + 1,
+                            ring.peek(g) if ring is not None else g,
+                            cur_tp, live=n_live)
+                    if preempt:
+                        break
+        if ring is not None:
+            with trace.span("graphx.pregel.spill"):
+                g = ring.materialize(g)    # exit fully resident
+        whole.set_metadata(supersteps=steps)
     return PregelResult(graph=g, supersteps=steps, metrics=all_metrics,
                         step=step)
+
+
+def superstep_jit(vprog: Callable, send_msg: Callable, gather: str, *,
+                  default_msg: Any, skip_stale: str | None,
+                  changed_fn: Callable | None, kernel_mode: str,
+                  incremental: bool, payload_bound: int | None,
+                  fuse_apply: Any) -> Callable:
+    """`_superstep` over these UDFs as `pregel`'s jitted step:
+    `step(g, transport=plan)`, the transport plan static.  The function is
+    named so that its compiled module is `jit_pregel_superstep`."""
+    def pregel_superstep(g, tstate=None, *, transport=None):
+        return _superstep(
+            g, tstate, vprog=vprog, send_msg=send_msg, gather=gather,
+            default_msg=default_msg, skip_stale=skip_stale,
+            changed_fn=changed_fn, kernel_mode=kernel_mode,
+            use_cache=incremental, payload_bound=payload_bound,
+            transport=transport, fuse_apply=fuse_apply)
+    return jax.jit(pregel_superstep, static_argnames=("transport",))
 
 
 def pregel_fused(

@@ -76,12 +76,14 @@ def triplet(x, ev, src_slot, dst_slot, live, tiles, tile_fn,
     None).  `xscale` is the narrow-resident scale plane (§2.4): per-32-row
     E8M0 exponents dequantizing an encoded `x` at the staging seam — in-VMEM
     on the kernel path, up-front on the oracle, bit-identical either way.
-    Returns (out [S, dm] f32, cnt [S] f32)."""
+    Returns (out [S, dm] f32, cnt [S] f32, chunks_live): the kernel's count
+    of chunks with a live edge, None on the oracle, which runs no grid."""
     m = _resolve(mode)
     if m == "ref":
-        return ref.fused_triplet(x, ev, src_slot, dst_slot, live, tile_fn,
-                                 num_segments, xscale=xscale, to=to,
-                                 reduce=reduce)
+        out, cnt = ref.fused_triplet(x, ev, src_slot, dst_slot, live, tile_fn,
+                                     num_segments, xscale=xscale, to=to,
+                                     reduce=reduce)
+        return out, cnt, None
     return _triplet.fused_triplet(
         x, ev, src_slot, dst_slot, live, tiles, tile_fn, num_segments, dm,
         xscale=xscale, to=to, reduce=reduce, use_src=use_src, use_dst=use_dst,

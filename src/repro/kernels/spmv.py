@@ -78,7 +78,7 @@ def spmv(
     else:                                            # skipStale at block level
         live = active_src_blocks[src_slot // vb]
     tiles = {"perm": perm, "chunk_out": chunk_dst, "chunk_in": chunk_src}
-    out, _ = fused_triplet(
+    out, _, _ = fused_triplet(
         x, w[:, None], src_slot, dst_slot, live, tiles, _linear_message,
         v_mir, x.shape[1], to="dst", reduce="sum", use_dst=False,
         eb=eb, vb=vb, interpret=interpret)

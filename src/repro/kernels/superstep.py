@@ -176,5 +176,6 @@ def fused_apply(
         out_shape=[jax.ShapeDtypeStruct((v_pad, dxv), jnp.float32),
                    jax.ShapeDtypeStruct((v_pad, 1), jnp.float32)],
         interpret=interpret,
+        name="fused_apply",
     )(chunk_out, act, cedge, cpay, xp, vidp, vmp)
     return newv[:num_slots], chg[:num_slots, 0]

@@ -358,7 +358,7 @@ def fused_triplet(
     eb: int = 512,
     vb: int = 512,
     interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """out[v] = reduce_{live e: out(e)=v} tile_fn(x[src(e)], ev[e], x[dst(e)])
 
     use_src / use_dst: whether tile_fn reads that endpoint's values.  An
@@ -367,7 +367,9 @@ def fused_triplet(
     side-aware unpack guarantees this).
 
     Returns (out [S, dm] f32 — reduce identity at empty slots,
-             cnt [S] f32 — live message count per slot).
+             cnt [S] f32 — live message count per slot,
+             chunks_live int32 — chunks with a live edge, the grid steps
+             that do work out of n_vb × n_chunks).
     """
     e = src_slot.shape[0]
     de = max(ev.shape[1], 1)
@@ -401,28 +403,35 @@ def fused_triplet(
                       ((0, n_vb * sb - xscale.shape[0]),
                        (0, max(1 - xscale.shape[1], 0))))
         scp = scp.reshape(n_vb, sb, scp.shape[1])
-    evp = jnp.concatenate(
-        [ev.reshape(e, -1), jnp.zeros((1, ev.shape[1]), ev.dtype)])
-    if ev.shape[1] == 0:
-        evp = jnp.zeros((e + 1, 1), jnp.float32)
-    sp = jnp.concatenate([src_slot.astype(jnp.int32), jnp.zeros((1,), jnp.int32)])
-    dp = jnp.concatenate([dst_slot.astype(jnp.int32), jnp.zeros((1,), jnp.int32)])
-    lp = jnp.concatenate([live, jnp.zeros((1,), bool)])
+    # the chunk-ordered streams the grid reads: gathers through `perm`,
+    # named so that a device trace can tell them from the kernel
+    with jax.named_scope("graphx.triplet_streams"):
+        evp = jnp.concatenate(
+            [ev.reshape(e, -1), jnp.zeros((1, ev.shape[1]), ev.dtype)])
+        if ev.shape[1] == 0:
+            evp = jnp.zeros((e + 1, 1), jnp.float32)
+        zero = jnp.zeros((1,), jnp.int32)
+        sp = jnp.concatenate([src_slot.astype(jnp.int32), zero])
+        dp = jnp.concatenate([dst_slot.astype(jnp.int32), zero])
+        lp = jnp.concatenate([live, jnp.zeros((1,), bool)])
 
-    # chunk-ordered edge streams; endpoint roles resolved from the grouping
-    chunk_src = chunk_out if to == "src" else chunk_in
-    chunk_dst = chunk_out if to == "dst" else chunk_in
-    pc = perm.reshape(n_chunks, eb)
-    oob = pc >= e
-    cs = jnp.where(oob, vb, sp[perm].reshape(n_chunks, eb)
-                   - (chunk_src * vb)[:, None]).astype(jnp.int32)
-    cd = jnp.where(oob, vb, dp[perm].reshape(n_chunks, eb)
-                   - (chunk_dst * vb)[:, None]).astype(jnp.int32)
-    co = cs if to == "src" else cd
-    clive = lp[perm].reshape(n_chunks, eb) & ~oob
-    cedge = chunk_rows([cs, cd, co], clive)
-    cev = jnp.swapaxes(evp[perm].reshape(n_chunks, eb, de), 1, 2)
-    act = clive.any(axis=1).astype(jnp.int32)     # chunk skip flag (dynamic)
+        # endpoint roles resolved from the grouping
+        chunk_src = chunk_out if to == "src" else chunk_in
+        chunk_dst = chunk_out if to == "dst" else chunk_in
+        pc = perm.reshape(n_chunks, eb)
+        oob = pc >= e
+        cs = jnp.where(oob, vb, sp[perm].reshape(n_chunks, eb)
+                       - (chunk_src * vb)[:, None]).astype(jnp.int32)
+        cd = jnp.where(oob, vb, dp[perm].reshape(n_chunks, eb)
+                       - (chunk_dst * vb)[:, None]).astype(jnp.int32)
+        co = cs if to == "src" else cd
+        clive = lp[perm].reshape(n_chunks, eb) & ~oob
+        cedge = chunk_rows([cs, cd, co], clive)
+        cev = jnp.swapaxes(evp[perm].reshape(n_chunks, eb, de), 1, 2)
+        act = clive.any(axis=1).astype(jnp.int32)  # chunk skip flag (dynamic)
+        # grid steps that do work: each live chunk runs in exactly one
+        # row of the (vertex block, chunk) grid
+        chunks_live = act.sum()
 
     sq = pl.Squeezed()
     in_specs = [
@@ -462,5 +471,6 @@ def fused_triplet(
         out_shape=[jax.ShapeDtypeStruct((v_pad, dm), jnp.float32),
                    jax.ShapeDtypeStruct((v_pad, 1), jnp.float32)],
         interpret=interpret,
+        name="fused_triplet",
     )(chunk_out, chunk_src, chunk_dst, act, *operands)
-    return out[:num_segments], cnt[:num_segments, 0]
+    return out[:num_segments], cnt[:num_segments, 0], chunks_live

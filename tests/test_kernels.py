@@ -51,7 +51,7 @@ def test_triplet_kernel_matches_oracle(reduce, to, e, v, dx, eb, vb):
     src, dst, live, x, ev = _flat_graph(e, v, dx, 2, seed=e + dx)
     out_s, in_s = (dst, src) if to == "dst" else (src, dst)
     tiles = _flat_tiles(out_s, in_s, np.ones(e, bool), v, eb=eb, vb=vb)
-    got, cnt = triplet_mod.fused_triplet(
+    got, cnt, chunks_live = triplet_mod.fused_triplet(
         jnp.asarray(x), jnp.asarray(ev), jnp.asarray(src), jnp.asarray(dst),
         jnp.asarray(live), tiles, _affine_msg, v, dx, to=to, reduce=reduce,
         eb=eb, vb=vb, interpret=True)
@@ -60,6 +60,10 @@ def test_triplet_kernel_matches_oracle(reduce, to, e, v, dx, eb, vb):
         jnp.asarray(live), _affine_msg, v, to=to, reduce=reduce)
     np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_want))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the grid counter: chunks holding a live edge
+    perm = np.asarray(tiles["perm"]).reshape(-1, eb)
+    lp = np.append(np.asarray(live), False)
+    assert int(chunks_live) == int(lp[np.minimum(perm, e)].any(axis=1).sum())
 
 
 def test_triplet_kernel_dead_edges_and_empty_segments():
@@ -68,11 +72,12 @@ def test_triplet_kernel_dead_edges_and_empty_segments():
     live = np.zeros(e, bool)                      # everything stale
     tiles = _flat_tiles(dst, src, np.ones(e, bool), v, eb=32, vb=16)
     for reduce in ("sum", "min", "max"):
-        out, cnt = triplet_mod.fused_triplet(
+        out, cnt, chunks_live = triplet_mod.fused_triplet(
             jnp.asarray(x), jnp.asarray(ev), jnp.asarray(src),
             jnp.asarray(dst), jnp.asarray(live), tiles, _affine_msg, v, 2,
             reduce=reduce, eb=32, vb=16, interpret=True)
         assert float(np.asarray(cnt).sum()) == 0.0
+        assert int(chunks_live) == 0              # every chunk skipped
         ident = triplet_mod.REDUCE_IDENTITY[reduce]
         np.testing.assert_array_equal(np.asarray(out),
                                       np.full((v, 2), ident, np.float32))
@@ -107,7 +112,7 @@ def test_triplet_tiles_per_partition_flatten():
     off = (np.arange(p, dtype=np.int32) * v_pad)[:, None]
     msg = lambda sv, evv, dv: sv * evv[:, :1] + dv
     for reduce in ("sum", "min"):
-        got, cnt = triplet_mod.fused_triplet(
+        got, cnt, _ = triplet_mod.fused_triplet(
             jnp.asarray(xpad.reshape(p * v_pad, dx)), jnp.asarray(ev.reshape(-1, 1)),
             jnp.asarray((src + off).reshape(-1)), jnp.asarray((dst + off).reshape(-1)),
             jnp.asarray(live.reshape(-1)), flat, msg, p * v_pad, dx,

@@ -144,3 +144,39 @@ def test_segment_sum_compiles_for_v5e(one_chip):
         return segment_sum.segment_sum(msgs, ids, num_segments)
 
     _compile(fn, spec((e, 1), jnp.float32), spec((e,), jnp.int32))
+
+
+def test_superstep_names_for_v5e(one_chip):
+    """The whole jitted PageRank superstep, compiled for the chip, keeps the
+    names a device trace is read by: the module is named after the
+    superstep, the two Pallas kernels are custom calls named `fused_triplet`
+    and `fused_apply`, and the chunk-stream gathers carry the
+    `graphx.triplet_streams` scope in their op names."""
+    import re
+
+    import numpy as np
+    from repro.core import Graph, algorithms
+    from repro.core.pregel import superstep_jit
+    from repro.core.transport import DENSE
+    from repro.data import rmat
+
+    gd = rmat(8, 8, seed=0)
+    g = Graph.from_edges(gd.src, gd.dst, num_partitions=P,
+                         edge_values={"w": np.ones(gd.num_edges, np.float32)})
+    g = algorithms.attach_out_degree(g)
+    g = g.mapV(lambda vid, v: {**v, "pr": jnp.float32(1.0)})
+    step = superstep_jit(
+        lambda vid, v, msg: {**v, "pr": 0.15 + 0.85 * msg["m"]},
+        lambda sv, ev, dv: {"m": sv["pr"] / sv["deg"] * ev["w"]}, "sum",
+        default_msg={"m": jnp.float32(0.0)}, skip_stale=None,
+        changed_fn=None, kernel_mode="pallas", incremental=True,
+        payload_bound=None, fuse_apply="auto")
+    spec = _specs(one_chip)
+    text = step.lower(jax.tree.map(lambda x: spec(x.shape, x.dtype), g),
+                      transport=DENSE).compile().as_text()
+    assert text.startswith("HloModule jit_pregel_superstep,")
+    calls = set(re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]* custom-call\(",
+                           text))
+    assert {"fused_triplet", "fused_apply"} <= calls
+    ops = re.findall(r'op_name="([^"]*)"', text)
+    assert any("/graphx.triplet_streams/" in o for o in ops)
