@@ -139,7 +139,7 @@ def grid_counts(g) -> dict:
     n_apply = int(s.tiles["apply_dst"]["chunk_out"].shape[1])
     return {
         "chunks_per_partition": n_chunks,
-        "triplet_grid_steps": p * -(-s.v_mir // vb) * p * n_chunks,
+        "triplet_grid_steps": p * n_chunks,
         "apply_grid_steps": p * -(-s.v_blk // vb) * p * n_apply,
         "tile_table_bytes": int(sum(a.nbytes for t in s.tiles.values()
                                     for a in t.values())),
@@ -260,8 +260,8 @@ def one_chip(args, kernel_mode, on_tpu):
             break
         scale += 1
     emit({"phase": "scale", **reached, "stop": why,
-          "note": ("the fused triplet grid is (vertex blocks x chunks) over "
-                   "all partitions; every step fetches its chunk's edge "
+          "note": ("the fused triplet grid is one step per chunk over all "
+                   "partitions; every step fetches its chunk's edge "
                    "blocks even when pl.when skips the compute")})
 
 

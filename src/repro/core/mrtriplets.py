@@ -1084,7 +1084,7 @@ def mr_triplets(
             vex, eex)
         if chunks_live is not None:
             # the §4.6 index scan at grid granularity: chunks the kernel
-            # swept, out of its static (vertex block × chunk) grid
+            # swept, out of its static grid of one step per chunk
             metrics["chunks_live"] = chunks_live
     else:
         zeros_elem = tree_zeros_like_elem(g.vdata, (nl, s.e_blk))
@@ -1417,11 +1417,10 @@ def apply_plan_of(g, vprog: Callable, send_msg: Callable,
 def sweep_grid(s, to: str = "dst") -> tuple[int, int]:
     """(chunks, grid steps) of one fused triplet sweep toward `to` over all
     partitions of structure `s`, from its tile tables' shapes: the kernel's
-    grid is every (vertex block, chunk) pair of the flat space that
-    `_fused_aggregate` builds, and a live chunk works in one of them."""
+    grid is 1-D over the chunks of the flat space that `_fused_aggregate`
+    builds, one step per chunk, and a step works when its chunk is live."""
     p, n_chunks = s.tiles[to]["chunk_out"].shape
-    n_vb = max(-(-s.v_mir // FUSED_VERTEX_BLOCK), 1)
-    return p * n_chunks, p * n_vb * p * n_chunks
+    return p * n_chunks, p * n_chunks
 
 
 def plan_of(g, map_fn: Callable, reduce: str = "sum", *,

@@ -186,6 +186,22 @@ def flatten_tiles(tiles, *, e_blk: int, n_vb: int) -> dict:
         chunk_in=(jnp.asarray(tiles["chunk_in"]) + off_b).reshape(-1))
 
 
+def grid_chunk_out(chunk_out: jnp.ndarray, pad: jnp.ndarray) -> jnp.ndarray:
+    """The output block of each step of `fused_triplet`'s 1-D grid [n_chunks]:
+    `chunk_out` on every real chunk, and on a padding chunk (`pad`: every
+    edge slot out of bounds) the block of the chunk before it.
+
+    The TPU writes an output block back when the block index changes and
+    does not read it in again, so along the grid the index must never go
+    back to a block it left.  Real chunks are already in order (out-block
+    major within a partition, partitions offset by `flatten_tiles`); only
+    each partition's padding tail, whose `chunk_out` is 0, breaks it, and a
+    running maximum with the padding at 0 restores it.  Padding chunks have
+    no live edge, so the block they carry gains nothing."""
+    return jax.lax.cummax(jnp.where(pad, 0, chunk_out).astype(jnp.int32),
+                          axis=0)
+
+
 # ----------------------------------------------------------------------------
 # Kernel
 # ----------------------------------------------------------------------------
@@ -280,18 +296,20 @@ def _make_kernel(tile_fn: Callable, reduce: str, use_src: bool,
         ss_ref = refs.pop(0) if have_scale and use_src else None
         ds_ref = refs.pop(0) if have_scale and use_dst else None
         out_ref, cnt_ref = refs
-        i = pl.program_id(0)      # aggregation-side block
-        c = pl.program_id(1)      # chunk
+        c = pl.program_id(0)      # chunk; its output block is cout_ref[c]
 
-        @pl.when(c == 0)
+        # the first chunk of each output block starts the resident block:
+        # block ids never decrease along the grid, so a block's chunks are
+        # consecutive steps and it is written back once, after its last
+        @pl.when(jnp.logical_or(
+            c == 0, cout_ref[c] != cout_ref[jnp.maximum(c - 1, 0)]))
         def _init():
             out_ref[...] = jnp.full_like(out_ref, ident)
             cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-        mine = cout_ref[c] == i
         # chunk skip (§4.6): a chunk whose edges are all dead — masked,
         # skipStale, or padding — never touches the tile pair.
-        @pl.when(jnp.logical_and(mine, act_ref[c] != 0))
+        @pl.when(act_ref[c] != 0)
         def _accumulate():
             vb = out_ref.shape[0]
             row = edge_ref[...]         # [4, Eb] src / dst / out slot, live
@@ -366,10 +384,15 @@ def fused_triplet(
     its place (PageRank reads only src) and must not touch it (the engine's
     side-aware unpack guarantees this).
 
+    The grid is 1-D over the flat chunks, one step each.  A step's output
+    block is its chunk's `chunk_out`, resident in VMEM while consecutive
+    chunks of that block accumulate into it (`grid_chunk_out`); a block no
+    chunk maps to gets the identity and a count of 0 after the call.
+
     Returns (out [S, dm] f32 — reduce identity at empty slots,
              cnt [S] f32 — live message count per slot,
              chunks_live int32 — chunks with a live edge, the grid steps
-             that do work out of n_vb × n_chunks).
+             that do work out of n_chunks).
     """
     e = src_slot.shape[0]
     de = max(ev.shape[1], 1)
@@ -429,40 +452,40 @@ def fused_triplet(
         cedge = chunk_rows([cs, cd, co], clive)
         cev = jnp.swapaxes(evp[perm].reshape(n_chunks, eb, de), 1, 2)
         act = clive.any(axis=1).astype(jnp.int32)  # chunk skip flag (dynamic)
-        # grid steps that do work: each live chunk runs in exactly one
-        # row of the (vertex block, chunk) grid
+        # grid steps that do work: the live chunks, one grid step each
         chunks_live = act.sum()
 
+    cout = grid_chunk_out(chunk_out, oob.all(axis=1))
     sq = pl.Squeezed()
     in_specs = [
-        pl.BlockSpec((sq, 4, eb), lambda i, c, co_, cs_, cd_, a: (c, 0, 0)),
-        pl.BlockSpec((sq, de, eb), lambda i, c, co_, cs_, cd_, a: (c, 0, 0)),
+        pl.BlockSpec((sq, 4, eb), lambda c, co_, cs_, cd_, a: (c, 0, 0)),
+        pl.BlockSpec((sq, de, eb), lambda c, co_, cs_, cd_, a: (c, 0, 0)),
     ]
     operands = [cedge, cev]
     if use_src:
         in_specs.append(pl.BlockSpec(
-            (vb, dx), lambda i, c, co_, cs_, cd_, a: (cs_[c], 0)))
+            (vb, dx), lambda c, co_, cs_, cd_, a: (cs_[c], 0)))
         operands.append(xp)
     if use_dst:
         in_specs.append(pl.BlockSpec(
-            (vb, dx), lambda i, c, co_, cs_, cd_, a: (cd_[c], 0)))
+            (vb, dx), lambda c, co_, cs_, cd_, a: (cd_[c], 0)))
         operands.append(xp)
     if have_scale and use_src:
         in_specs.append(pl.BlockSpec(
-            (sq, sb, dx), lambda i, c, co_, cs_, cd_, a: (cs_[c], 0, 0)))
+            (sq, sb, dx), lambda c, co_, cs_, cd_, a: (cs_[c], 0, 0)))
         operands.append(scp)
     if have_scale and use_dst:
         in_specs.append(pl.BlockSpec(
-            (sq, sb, dx), lambda i, c, co_, cs_, cd_, a: (cd_[c], 0, 0)))
+            (sq, sb, dx), lambda c, co_, cs_, cd_, a: (cd_[c], 0, 0)))
         operands.append(scp)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,                    # chunk_out/src/dst + act
-        grid=(n_vb, n_chunks),
+        num_scalar_prefetch=4,                    # grid cout, src/dst + act
+        grid=(n_chunks,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((vb, dm), lambda i, c, co_, cs_, cd_, a: (i, 0)),
-            pl.BlockSpec((vb, 1), lambda i, c, co_, cs_, cd_, a: (i, 0)),
+            pl.BlockSpec((vb, dm), lambda c, co_, cs_, cd_, a: (co_[c], 0)),
+            pl.BlockSpec((vb, 1), lambda c, co_, cs_, cd_, a: (co_[c], 0)),
         ],
     )
     out, cnt = pl.pallas_call(
@@ -470,7 +493,16 @@ def fused_triplet(
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((v_pad, dm), jnp.float32),
                    jax.ShapeDtypeStruct((v_pad, 1), jnp.float32)],
+        # sequential: an output block accumulates across consecutive steps
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="fused_triplet",
-    )(chunk_out, chunk_src, chunk_dst, act, *operands)
-    return out[:num_segments], cnt[:num_segments, 0], chunks_live
+    )(cout, chunk_src, chunk_dst, act, *operands)
+    # blocks no chunk maps to were never written: select, so that whatever
+    # the buffer held there (NaN included) cannot leak into the result
+    visited = jnp.zeros((n_vb,), bool).at[cout].set(True)
+    vis = jnp.repeat(visited, vb)[:num_segments, None]
+    out = jnp.where(vis, out[:num_segments], REDUCE_IDENTITY[reduce])
+    cnt = jnp.where(vis, cnt[:num_segments], 0.0)
+    return out, cnt[:, 0], chunks_live

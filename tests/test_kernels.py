@@ -128,6 +128,101 @@ def test_triplet_tiles_per_partition_flatten():
             np.testing.assert_array_equal(cnt[q], np.asarray(cwant))
 
 
+def _grid_order_case(to, seed=5):
+    """Four partitions on the flat space, built so that the 1-D chunk grid
+    meets every case its block order has to survive:
+
+      partition 0: no aggregation slot in block 1 (a middle block no chunk
+                   maps to), and 40 edges on one (out 2, in 0) block pair,
+                   split over consecutive chunks of eb = 16;
+      partition 1: no edge at all, so nothing but padding chunks;
+      partition 2: edges, none of them live;
+      partition 3: few edges, so a tail of padding chunks.
+    """
+    p, e_blk, v_mir, dx = 4, 96, 64, 2
+    eb = vb = 16
+    rng = np.random.default_rng(seed)
+    out_s = rng.integers(0, v_mir, (p, e_blk)).astype(np.int32)
+    in_s = rng.integers(0, v_mir, (p, e_blk)).astype(np.int32)
+    out_s[0] = np.where(out_s[0] // vb == 1, out_s[0] + vb, out_s[0])
+    out_s[0, :40] = rng.integers(2 * vb, 3 * vb, 40)
+    in_s[0, :40] = rng.integers(0, vb, 40)
+    mask = np.ones((p, e_blk), bool)
+    mask[1] = False
+    mask[3, 10:] = False
+    live = mask & (rng.random((p, e_blk)) > 0.25)
+    live[2] = False
+    tiles = triplet_mod.build_triplet_tiles(out_s, in_s, mask, v_mir,
+                                            eb=eb, vb=vb)
+    co = tiles["chunk_out"]
+    real = (tiles["perm"] < e_blk).any(axis=2)
+    assert 1 not in co[0][real[0]]
+    assert ((co[0] == 2) & (tiles["chunk_in"][0] == 0)).sum() >= 3
+    assert not real[1].any() and not real[3][-1]
+
+    off = (np.arange(p, dtype=np.int32) * v_mir)[:, None]
+    out_f, in_f = (out_s + off).reshape(-1), (in_s + off).reshape(-1)
+    src, dst = (in_f, out_f) if to == "dst" else (out_f, in_f)
+    x = rng.integers(-4, 5, (p * v_mir, dx)).astype(np.float32)
+    ev = rng.integers(1, 4, (p * e_blk, 2)).astype(np.float32)
+    flat = triplet_mod.flatten_tiles(tiles, e_blk=e_blk, n_vb=v_mir // vb)
+    return dict(x=x, ev=ev, src=src, dst=dst, live=live.reshape(-1),
+                tiles=flat, v=p * v_mir, dx=dx, eb=eb, vb=vb)
+
+
+@pytest.mark.parametrize("reduce", ["sum", "min", "max"])
+@pytest.mark.parametrize("to", ["dst", "src"])
+def test_triplet_chunk_grid_block_order(reduce, to):
+    """The 1-D chunk grid over a multi-partition flat table: padding tails,
+    a block no chunk maps to, a run split over consecutive chunks of one
+    block, a partition with nothing live — bit-exact to the oracle."""
+    k = _grid_order_case(to)
+    args = [jnp.asarray(k[n]) for n in ("x", "ev", "src", "dst", "live")]
+    got, cnt, _ = triplet_mod.fused_triplet(
+        *args, k["tiles"], _affine_msg, k["v"], k["dx"], to=to,
+        reduce=reduce, eb=k["eb"], vb=k["vb"], interpret=True)
+    want, cnt_want = ref.fused_triplet(*args, _affine_msg, k["v"], to=to,
+                                       reduce=reduce)
+    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_want))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the unvisited middle block of partition 0, and all of partition 1
+    ident = triplet_mod.REDUCE_IDENTITY[reduce]
+    for rows in (slice(16, 32), slice(64, 128)):
+        np.testing.assert_array_equal(np.asarray(got)[rows],
+                                      np.full((16 if rows.start == 16 else 64,
+                                               k["dx"]), ident, np.float32))
+        assert not np.asarray(cnt)[rows].any()
+
+
+@pytest.mark.parametrize("source", ["dst", "src", "random"])
+def test_grid_chunk_out_never_decreases(source):
+    """The chip writes an output block back when the grid leaves it and
+    never reads it in again, so the grid's block ids must never decrease;
+    interpret mode re-reads a revisited block, so only this invariant
+    guards the order on CPU.  On every real chunk they are its chunk_out."""
+    if source == "random":   # uneven partitions, every one padded but one
+        p, e_blk, v_mir = 5, 300, 100
+        rng = np.random.default_rng(11)
+        mask = rng.random((p, e_blk)) < np.linspace(0.1, 1.0, p)[:, None]
+        tiles = triplet_mod.build_triplet_tiles(
+            rng.integers(0, v_mir, (p, e_blk)),
+            rng.integers(0, v_mir, (p, e_blk)), mask, v_mir, eb=16, vb=16)
+        flat = triplet_mod.flatten_tiles(tiles, e_blk=e_blk,
+                                         n_vb=-(-v_mir // 16))
+        e = p * e_blk
+    else:
+        k = _grid_order_case(source)
+        flat, e = k["tiles"], k["src"].size
+    co = np.asarray(flat["chunk_out"])
+    pad = (np.asarray(flat["perm"]).reshape(co.size, -1) >= e).all(axis=1)
+    cout = np.asarray(triplet_mod.grid_chunk_out(jnp.asarray(co),
+                                                 jnp.asarray(pad)))
+    assert pad.any() and (~pad).any()
+    assert (np.diff(co) < 0).any()        # the stored tables break the order
+    assert (np.diff(cout) >= 0).all()
+    np.testing.assert_array_equal(cout[~pad], co[~pad])
+
+
 def _build_engine_graph(seed=0, p=4, scale=6, ef=4, payload_dim=0):
     from repro.core import Graph
     from repro.data import rmat

@@ -115,12 +115,14 @@ def test_sync_spans_carry_the_grid_counter(traced):
     g, _, _, spans = traced
     from repro.core.mrtriplets import sweep_grid
     chunks, grid_steps = sweep_grid(g.s)
+    # the kernel's grid is one step per chunk of the flat space
+    assert grid_steps == chunks == g.s.tiles["dst"]["chunk_out"].size
     syncs = [s for s in spans if s[0] == "graphx.pregel.sync"]
     assert syncs
     for s in syncs:
         a = s[3]
         assert (a["chunks"], a["grid_steps"]) == (chunks, grid_steps)
-        assert 0 <= a["chunks_live"] <= a["chunks"] <= a["grid_steps"]
+        assert 0 <= a["chunks_live"] <= a["grid_steps"] == a["chunks"]
     # PageRank sweeps every chunk that holds an edge, every superstep
     live = [s[3]["chunks_live"] for s in syncs]
     assert max(live) > 0
