@@ -17,9 +17,10 @@ the kernels (`tpu_custom_call`).  The sweep starts at --scale and goes up one
 scale at a time while one superstep stays under STEP_BUDGET_S seconds; its
 last line says where and why it stopped.
 
---chips 4 runs only the SPMD executor: the same PageRank and CC supersteps
-under `shard_map` with `SpmdExchange` across four devices, one partition
-each, checked against the same references.
+--chips 4 runs only the SPMD executor: the same PageRank and CC, through
+`algorithms` on a graph that `Graph.place` put one partition per device,
+checked against the same references; each superstep must hold an
+all-to-all.
 
 Every line but the last is one JSON object for one phase.  The last line is
 `{"ok": true, "device": {...}}` and appears only when every check passed on
@@ -29,7 +30,6 @@ a TPU.  Without a TPU the script exits non-zero and prints no result; only
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 import sys
@@ -156,8 +156,10 @@ def step_program(res, transport):
     device memory the program needs."""
     compiled = res.step.lower(res.graph, transport=transport).compile()
     mem = compiled.memory_analysis()
+    text = compiled.as_text()
     return {
-        "tpu_custom_call": "tpu_custom_call" in compiled.as_text(),
+        "tpu_custom_call": "tpu_custom_call" in text,
+        "all_to_all": "all-to-all" in text,
         "program_bytes": None if mem is None else {
             "argument": mem.argument_size_in_bytes,
             "output": mem.output_size_in_bytes,
@@ -266,91 +268,45 @@ def one_chip(args, kernel_mode, on_tpu):
 
 
 def four_chips(args, kernel_mode, on_tpu):
-    """PageRank and CC supersteps under jit(shard_map) with SpmdExchange:
-    one partition per device, the host loop driving static supersteps."""
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as PS
-    from repro.core import Graph, SpmdExchange, algorithms as alg
-    from repro.core.pregel import _superstep
+    """PageRank and CC through `algorithms` on a graph that `Graph.place`
+    put one partition per device: each superstep runs under shard_map
+    with SpmdExchange, and the host loop reads values summed over the
+    devices."""
+    from repro.core import Graph, algorithms as alg
+    from repro.core.transport import resolve_transport
     from repro.data import rmat, symmetrize
-    from repro.utils.spmd import make_mesh, shard_map
 
-    mesh = make_mesh((PARTITIONS,), ("parts",),
-                     devices=jax.devices()[:PARTITIONS])
+    clock = CompileClock()
+    transport = resolve_transport(None)
+    devices = jax.devices()[:PARTITIONS]
 
-    def spmd(g):
-        g = dataclasses.replace(g, ex=SpmdExchange(p=PARTITIONS,
-                                                   axis_name="parts"),
-                                host=None)
-        return jax.device_put(g, jax.tree.map(
-            lambda x: NamedSharding(mesh, PS("parts")), g))
-
-    def loop(g, name, max_steps, **kw):
-        def body(gg):
-            g2, live, _ = _superstep(gg, kernel_mode=kernel_mode,
-                                     use_cache=True, **kw)
-            return g2, jax.lax.psum(live, "parts")
-        step = jax.jit(shard_map(body, mesh, (PS("parts"),),
-                                 (PS("parts"), PS())))
-        # one executable per graph structure (the view turns warm after the
-        # first superstep), compiled ahead so the loop runs no compile
-        programs, compile_s = {}, 0.0
-        t0 = time.time()
-        steps = 0
-        for _ in range(max_steps):
-            key = jax.tree.structure(g)
-            if key not in programs:
-                tc = time.time()
-                programs[key] = step.lower(g).compile()
-                compile_s += time.time() - tc
-            g, live = programs[key](g)
-            steps += 1
-            if int(live) == 0:
-                break
-        jax.block_until_ready(g.vdata)
-        hlo = programs[jax.tree.structure(g)].as_text()
-        leaf = jax.tree.leaves(g.vdata)[0]
-        shards = leaf.addressable_shards
-        devices = sorted({s.device.id for s in shards})
-        row = {"phase": name, "supersteps": steps, "compile_s": compile_s,
-               "run_s": time.time() - t0 - compile_s,
-               "shard_devices": devices,
-               "shard_rows": sorted({s.data.shape[0] for s in shards}),
-               "all_to_all": "all-to-all" in hlo,
-               "tpu_custom_call": "tpu_custom_call" in hlo,
-               "peak_bytes_in_use": peak_bytes()}
-        if len(devices) != PARTITIONS or row["shard_rows"] != [1]:
+    def run_placed(name, fn, graph, **kw):
+        res, row = run_algorithm(name, fn, graph.place(devices), clock,
+                                 transport, on_tpu, kernel_mode=kernel_mode,
+                                 **kw)
+        shards = jax.tree.leaves(res.graph.vdata)[0].addressable_shards
+        row.update(scale=args.scale,
+                   shard_devices=sorted({s.device.id for s in shards}),
+                   shard_rows=sorted({s.data.shape[0] for s in shards}))
+        if (len(row["shard_devices"]) != PARTITIONS
+                or row["shard_rows"] != [1]):
             raise AssertionError(f"{name}: partitions not one per device: "
                                  f"{row}")
         if not row["all_to_all"]:
             raise AssertionError(f"{name}: no all-to-all in the step")
-        if on_tpu and not row["tpu_custom_call"]:
-            raise AssertionError(f"{name}: no Pallas kernel in the step")
-        return g, row
+        return res, row
 
     gd = rmat(args.scale, 16, seed=args.seed)
     g = Graph.from_edges(gd.src, gd.dst, num_partitions=PARTITIONS)
-    g = alg.attach_out_degree(g, kernel_mode).mapV(
-        lambda vid, v: {**v, "pr": jnp.float32(1.0)})
-    g, row = loop(
-        spmd(g), "spmd_pagerank", PR_ITERS,
-        vprog=lambda vid, v, msg: {**v, "pr": 0.15 + 0.85 * msg["m"]},
-        send_msg=lambda sv, ev, dv: {"m": sv["pr"] / sv["deg"] * ev["w"]},
-        gather="sum", default_msg={"m": jnp.float32(0.0)}, skip_stale=None,
-        changed_fn=None)
-    row["scale"] = args.scale
-    check_pagerank(g, gd, row)
+    res, row = run_placed("spmd_pagerank", alg.pagerank, g,
+                          num_iters=PR_ITERS)
+    check_pagerank(res.graph, gd, row)
+    del g, res
 
     sd = symmetrize(gd)
     sg = Graph.from_edges(sd.src, sd.dst, num_partitions=PARTITIONS)
-    sg = sg.mapV(lambda vid, v: {"cc": vid})
-    sg, row = loop(
-        spmd(sg), "spmd_cc", 100,
-        vprog=lambda vid, v, msg: {"cc": jnp.minimum(v["cc"], msg["m"])},
-        send_msg=lambda sv, ev, dv: {"m": sv["cc"]}, gather="min",
-        default_msg={"m": alg.IMAX}, skip_stale="out", changed_fn=None)
-    row["scale"] = args.scale
-    check_cc(sg, sd, row)
+    res, row = run_placed("spmd_cc", alg.connected_components, sg)
+    check_cc(res.graph, sd, row)
 
 
 def main(argv=None) -> int:

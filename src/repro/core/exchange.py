@@ -46,6 +46,9 @@ from . import transport as transport_mod
 from . import wire as wire_mod
 from .wire import WireCodec, make_codec
 
+# the named scope over every collective SpmdExchange runs
+COLLECTIVE_SCOPE = "graphx.collective"
+
 
 class Exchange:
     """Executor interface. `p` is the number of graph partitions."""
@@ -92,6 +95,11 @@ class Exchange:
         (active fraction, overflow) go through this so they are uniform
         across the mesh — a device-divergent dense/ragged choice would give
         the collectives mismatched shapes."""
+        return x
+
+    def pmax(self, x: jnp.ndarray) -> jnp.ndarray:
+        """Mesh-global maximum of a per-executor quantity (identity where
+        the executor holds every partition, as `psum`)."""
         return x
 
     def home_rows(self, nl: int) -> jnp.ndarray:
@@ -200,6 +208,9 @@ class SpmdExchange(Exchange):
     contract transpose is exactly `lax.all_to_all` splitting the *second*
     axis and concatenating on the first — the collective moves each
     [blk, blk, ...] tile x[q, p] to device p.
+
+    Every collective it runs lies under the `graphx.collective` scope, so
+    a device trace can tell the time the chips spend exchanging apart.
     """
 
     p: int
@@ -210,16 +221,18 @@ class SpmdExchange(Exchange):
         # local x: [P_loc=1, P, ...].  Tiled all_to_all over axis 1: device p
         # sends tile q to device q and receives tile (q -> position q), i.e.
         # out[0, q] = x_global[q, p] — exactly the transpose contract.
-        return jax.lax.all_to_all(
-            x, self.axis_name, split_axis=1, concat_axis=1, tiled=True
-        )
+        with jax.named_scope(COLLECTIVE_SCOPE):
+            return jax.lax.all_to_all(
+                x, self.axis_name, split_axis=1, concat_axis=1, tiled=True)
 
     def ppermute(self, x: jnp.ndarray, shift: int) -> jnp.ndarray:
         s = shift % self.p
         if s == 0:
             return x
-        return jax.lax.ppermute(
-            x, self.axis_name, [(i, (i + s) % self.p) for i in range(self.p)])
+        with jax.named_scope(COLLECTIVE_SCOPE):
+            return jax.lax.ppermute(
+                x, self.axis_name,
+                [(i, (i + s) % self.p) for i in range(self.p)])
 
     def ring_transpose(self, x: jnp.ndarray) -> jnp.ndarray:
         # local x: [1, P, ...] (one partition per device — the ring schedule
@@ -236,9 +249,10 @@ class SpmdExchange(Exchange):
         for d in range(p):
             blk = jax.lax.dynamic_slice_in_dim(x, (r + d) % p, 1, axis=1)
             if d:
-                blk = jax.lax.ppermute(
-                    blk, self.axis_name,
-                    [(i, (i + d) % p) for i in range(p)])
+                with jax.named_scope(COLLECTIVE_SCOPE):
+                    blk = jax.lax.ppermute(
+                        blk, self.axis_name,
+                        [(i, (i + d) % p) for i in range(p)])
             out = jax.lax.dynamic_update_slice_in_dim(
                 out, blk, (r - d + p) % p, axis=1)
         return out
@@ -249,11 +263,17 @@ class SpmdExchange(Exchange):
         # asserts on in the HLO (vs P point-to-point payloads) — then a
         # leading unit axis to restore the [nl, P, B, ...] local layout.
         assert x.shape[0] == 1, x.shape
-        return jax.lax.all_gather(
-            x, self.axis_name, axis=0, tiled=True)[None]
+        with jax.named_scope(COLLECTIVE_SCOPE):
+            return jax.lax.all_gather(
+                x, self.axis_name, axis=0, tiled=True)[None]
 
     def psum(self, x: jnp.ndarray) -> jnp.ndarray:
-        return jax.lax.psum(x, self.axis_name)
+        with jax.named_scope(COLLECTIVE_SCOPE):
+            return jax.lax.psum(x, self.axis_name)
+
+    def pmax(self, x: jnp.ndarray) -> jnp.ndarray:
+        with jax.named_scope(COLLECTIVE_SCOPE):
+            return jax.lax.pmax(x, self.axis_name)
 
     def home_rows(self, nl: int) -> jnp.ndarray:
         base = jax.lax.axis_index(self.axis_name).astype(jnp.int32)

@@ -21,20 +21,24 @@ UDF conventions (all per-element; the engine vmaps):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from . import partition as part_mod
 from .collections import Col
-from .exchange import Exchange, LocalExchange
-from .mrtriplets import mr_triplets
+from . import trace
+from .exchange import Exchange, LocalExchange, SpmdExchange
+from .mrtriplets import metrics_across, mr_triplets
 from .tree import elem_spec, gather_rows, tree_where, vmap2
 from . import analysis
 from . import view as view_mod
 from .view import GraphView, WireLog
+from ..utils.spmd import make_mesh, shard_map
 
 
 @jax.tree_util.register_pytree_node_class
@@ -116,6 +120,46 @@ class StructArrays:
             b_width=s.b_width)
 
 
+PARTS_AXIS = "parts"
+# shard_map specs of `per_partition`'s outputs: one row per partition, or
+# one value already reduced over the devices
+PARTS = PartitionSpec(PARTS_AXIS)
+WHOLE = PartitionSpec()
+
+
+def per_partition(fn: Callable, out_specs) -> Callable:
+    """`fn(g, *args)` run on each device's partitions of a placed graph.
+
+    For a graph that `Graph.place` put one partition per device, the call
+    runs `fn` under `shard_map` over the graph's mesh: `fn` sees the local
+    partition (leading axis 1) with the graph's `SpmdExchange`, and the
+    other arguments replicated.  `out_specs` is a prefix of fn's output:
+    `PARTS` where it holds one row per partition, `WHOLE` where `fn` has
+    already reduced it over the devices (`g.ex.psum`, `metrics_across`).
+    Graphs in the output come back placed.  For any other graph the call is
+    `fn(g, *args)` itself, with nothing added to its program."""
+    @functools.wraps(fn)
+    def run(g, *args):
+        if g.mesh is None:
+            return fn(g, *args)
+
+        def local(g, *args):
+            return _with_mesh(fn(dataclasses.replace(g, mesh=None), *args),
+                              None)
+        out = shard_map(local, g.mesh, (PARTS,) + (WHOLE,) * len(args),
+                        out_specs)(g, *args)
+        return _with_mesh(out, g.mesh)
+    return run
+
+
+def _with_mesh(tree, mesh):
+    """`tree` with every Graph in it carrying `mesh`."""
+    return jax.tree.map(
+        lambda x: (dataclasses.replace(x, mesh=mesh)
+                   if isinstance(x, Graph) else x),
+        tree, is_leaf=lambda x: isinstance(x, Graph))
+
+
 def _degree_msg(sv, ev, dv):
     """Stable module-level UDF: fused-path caches (tile_fn, kernel compiles)
     key on the UDF's object identity, so per-call lambdas would defeat them."""
@@ -164,15 +208,49 @@ class Graph:
     # tracing — unlike any check on the vmask values or object identity.
     # Defaults to False: hand-rolled Graphs safely take the general path.
     vmask_full: bool = dataclasses.field(default=False)     # static
+    # the 1-D mesh ("parts") of a graph placed one partition per device by
+    # `place`; None for a graph whose partitions share one device.
+    mesh: jax.sharding.Mesh | None = dataclasses.field(default=None)  # static
 
     def tree_flatten(self):
         return ((self.s, self.vdata, self.edata, self.vmask, self.emask,
                  self.active, self.view, self.wire_log),
-                (self.ex, self.host, self.vmask_full))
+                (self.ex, self.host, self.vmask_full, self.mesh))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, ex=aux[0], host=aux[1], vmask_full=aux[2])
+        return cls(*children, ex=aux[0], host=aux[1], vmask_full=aux[2],
+                   mesh=aux[3])
+
+    # ------------------------------------------------------------ placement
+    @property
+    def num_devices(self) -> int:
+        """Devices the partitions live on: 1 unless the graph is placed."""
+        return 1 if self.mesh is None else self.mesh.size
+
+    def place(self, devices) -> "Graph":
+        """The graph with partition i on `devices[i]`, one per device
+        (DESIGN.md §4.7).  Every array's leading partition axis is sharded
+        over a 1-D mesh of the devices, named "parts", and the exchange
+        becomes `SpmdExchange` with the graph's wire codec, so routes move
+        by all-to-all between the devices.  `pregel` and the eager operators
+        run the placed graph through `per_partition`: `algorithms.pagerank`,
+        `connected_components` and `sssp` take it as they take any graph."""
+        devices = list(devices)
+        if self.mesh is not None:
+            raise ValueError("the graph is placed already")
+        if len(devices) != self.s.p:
+            raise ValueError(f"place: {self.s.p} partitions need "
+                             f"{self.s.p} devices, got {len(devices)}")
+        with trace.span("graphx.place", devices=len(devices)):
+            mesh = make_mesh((self.s.p,), (PARTS_AXIS,), devices=devices)
+            g = dataclasses.replace(
+                self, mesh=mesh,
+                ex=SpmdExchange(p=self.s.p, axis_name=PARTS_AXIS,
+                                wire=self.ex.wire))
+            g = jax.device_put(g, NamedSharding(mesh, PARTS))
+            jax.block_until_ready(g)
+        return g
 
     def replace(self, **kw) -> "Graph":
         """dataclasses.replace with view hygiene: rewriting `vdata` or
@@ -347,6 +425,8 @@ class Graph:
         callable `changed(old_vval, new_vval) -> bool` is the caller's
         per-vertex certificate — a transform touching 1% of vertices then
         re-ships 1%."""
+        if self.mesh is not None:
+            return _placed_map_vertices(f, changed)(self)
         new_vdata = vmap2(f)(self.s.home_vid, self.vdata)
         rewrites = analysis.analyze_rewrites(
             f, (jax.ShapeDtypeStruct((), self.s.home_vid.dtype),
@@ -592,7 +672,21 @@ class Graph:
         back dense), or "auto" (hysteresis on the psummed active fraction;
         transport_state carries the previous decision).  Transports change
         bytes, never values.
+
+        On a placed graph (`place`) the call runs per partition under one
+        jitted `shard_map`; its metrics hold the array entries summed over
+        the devices (`metrics_across`).  `cache`, `transport_state` and
+        `epred` are not supported there.
         """
+        if self.mesh is not None:
+            if (cache is not None or transport_state is not None
+                    or epred is not None):
+                raise NotImplementedError(
+                    "mrTriplets on a placed graph takes no cache, "
+                    "transport_state or epred")
+            return _placed_mr_triplets(
+                map_fn, reduce, to, skip_stale, kernel_mode, force_need,
+                payload_bound, transport)(self)
         values, exists, view, metrics = mr_triplets(
             self, map_fn, reduce, to=to, skip_stale=skip_stale,
             cache=cache, kernel_mode=kernel_mode,
@@ -630,3 +724,28 @@ class Graph:
         m = np.asarray(mask)
         return (np.asarray(svid)[m], np.asarray(dvid)[m],
                 jax.tree.map(lambda e: np.asarray(e)[m], edata))
+
+
+@functools.lru_cache(maxsize=64)
+def _placed_map_vertices(f, changed):
+    """`g.mapV(f, changed=changed)` per partition of a placed graph, jitted
+    once per (f, changed)."""
+    def placed_mapV(g):
+        return g.mapV(f, changed=changed)
+    return jax.jit(per_partition(placed_mapV, PARTS))
+
+
+@functools.lru_cache(maxsize=64)
+def _placed_mr_triplets(map_fn, reduce, to, skip_stale, kernel_mode,
+                        force_need, payload_bound, transport):
+    """`g.mrTriplets(...)` per partition of a placed graph, jitted once per
+    set of static arguments: (values, exists, graph', metrics summed over
+    the devices)."""
+    def placed_mrTriplets(g):
+        values, exists, g2, metrics = g.mrTriplets(
+            map_fn, reduce, to=to, skip_stale=skip_stale,
+            kernel_mode=kernel_mode, force_need=force_need,
+            payload_bound=payload_bound, transport=transport)
+        return values, exists, g2, metrics_across(metrics, g.ex)
+    return jax.jit(per_partition(placed_mrTriplets,
+                                 (PARTS, PARTS, PARTS, WHOLE)))

@@ -173,6 +173,19 @@ class ShipMetrics:
             bytes_link_modeled=(self.bytes_link_modeled
                                 + other.bytes_link_modeled))
 
+    def across(self, ex: Exchange) -> "ShipMetrics":
+        """The record summed over the executors of `ex`: byte and count
+        fields add, the plan and occupancy facts take the largest, as in
+        `merge`.  The identity where one executor holds every partition."""
+        add = ("effective_bytes", "n_shipped", "bytes_accounted",
+               "bytes_shipped", "overflow", "wire_faults", "degraded",
+               "bytes_link_modeled")
+        return dataclasses.replace(self, **{
+            f.name: (ex.psum if f.name in add else ex.pmax)(
+                getattr(self, f.name))
+            for f in dataclasses.fields(self)
+            if f.name not in ("wire_bytes", "route_width")})
+
     def tree_flatten(self):
         return ((self.effective_bytes, self.n_shipped, self.bytes_accounted,
                  self.bytes_shipped, self.ragged, self.route_active_max,
@@ -186,6 +199,25 @@ class ShipMetrics:
                    overflow=children[6], wire_faults=children[7],
                    degraded=children[8], route_active_frac=children[9],
                    bytes_link_modeled=children[10])
+
+
+def metrics_across(metrics: dict, ex: Exchange) -> dict:
+    """The array entries of an mr_triplets metrics dict summed over the
+    executors of `ex`, so that the host reads one value of each: counts and
+    bytes add; the plan flags (`ragged`, `transport_state`) take the
+    largest.  Static entries (names, counts known at trace time) and the
+    per-partition `emask_pushed` are left out."""
+    out = {}
+    for k, v in metrics.items():
+        if isinstance(v, (str, int)) or k == "emask_pushed":
+            continue
+        if isinstance(v, ShipMetrics):
+            out[k] = v.across(ex)
+        elif k in ("ragged", "transport_state"):
+            out[k] = ex.pmax(v)
+        else:
+            out[k] = ex.psum(v)
+    return out
 
 
 def _route_ship(ex: Exchange, sendbuf: Any, flags: jnp.ndarray, *,

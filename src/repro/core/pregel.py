@@ -44,9 +44,9 @@ from . import analysis
 from . import trace
 from . import transport as transport_mod
 from . import view as view_mod
-from .graph import Graph
+from .graph import PARTS, WHOLE, Graph, per_partition
 from .mrtriplets import (_plan_apply, apply_plan_of, fused_apply_home,
-                         mr_triplets)
+                         metrics_across, mr_triplets)
 from .tree import elem_spec, tree_changed, tree_where, vmap2
 
 
@@ -209,16 +209,28 @@ def pregel(
     guards every ragged step).  The per-superstep metrics record the
     decision next to `plan` ("transport", "transport_cap", "ragged").
 
-    Spans (core/trace.py): `graphx.pregel` over the call (`supersteps` set
-    at exit), and in it `graphx.pregel.plan` before the loop, then per
-    superstep `graphx.pregel.dispatch` (`first=1` where the call traced a
-    program), `graphx.pregel.sync` (the host's reads of the step's
-    results) and, where they run, `graphx.pregel.spill`, `.record`,
+    On a graph placed one partition per device (`Graph.place`) the step
+    runs per partition and the loop reads values summed over the devices;
+    `working_set_frac` and `checkpoint` are not supported there.
+
+    Spans (core/trace.py): `graphx.pregel` over the call (`devices`, and
+    `supersteps` set at exit), and in it `graphx.pregel.plan` before the
+    loop, then per superstep `graphx.pregel.dispatch` (`first=1` where the
+    call traced a program), `graphx.pregel.sync` (the host's reads of the
+    step's results) and, where they run, `graphx.pregel.spill`, `.record`,
     `.adapt` and `.checkpoint`.  Only while a profiler trace runs does the
     sync span also read the fused triplet sweep's `chunks_live` and carry
-    it beside the grid's static `chunks` and `grid_steps`."""
+    it beside the grid's static `chunks` and `grid_steps`, and, on a placed
+    graph, `bytes_crossing`: the bytes the step's routes moved from one
+    device to another (`ShipMetrics.bytes_link_modeled`, summed)."""
 
-    with trace.span("graphx.pregel") as whole:
+    if g.mesh is not None and (working_set_frac is not None
+                               or checkpoint is not None):
+        raise NotImplementedError(
+            "pregel on a placed graph runs with every partition resident "
+            "and without checkpoints: working_set_frac and checkpoint are "
+            "not supported there")
+    with trace.span("graphx.pregel", devices=g.num_devices) as whole:
         with trace.span("graphx.pregel.plan"):
             step = superstep_jit(
                 vprog, send_msg, gather, default_msg=default_msg,
@@ -330,6 +342,10 @@ def pregel(
                     sync.set_metadata(
                         chunks_live=int(metrics["chunks_live"]),
                         chunks=grid[0], grid_steps=grid[1])
+                # bytes the step's routes moved from one chip to another
+                if g.mesh is not None and trace.active():
+                    sync.set_metadata(bytes_crossing=int(
+                        fwd.bytes_link_modeled + back.bytes_link_modeled))
             if overflow_fallbacks:
                 _log.warning(
                     "pregel superstep %d: ragged transport overflowed its "
@@ -425,14 +441,21 @@ def superstep_jit(vprog: Callable, send_msg: Callable, gather: str, *,
                   fuse_apply: Any) -> Callable:
     """`_superstep` over these UDFs as `pregel`'s jitted step:
     `step(g, transport=plan)`, the transport plan static.  The function is
-    named so that its compiled module is `jit_pregel_superstep`."""
+    named so that its compiled module is `jit_pregel_superstep`.
+
+    On a placed graph the step runs per partition under `shard_map`
+    (`per_partition`), and `live` and the metrics come back summed over
+    the devices; on any other graph it is `_superstep` alone."""
     def pregel_superstep(g, tstate=None, *, transport=None):
-        return _superstep(
-            g, tstate, vprog=vprog, send_msg=send_msg, gather=gather,
-            default_msg=default_msg, skip_stale=skip_stale,
-            changed_fn=changed_fn, kernel_mode=kernel_mode,
-            use_cache=incremental, payload_bound=payload_bound,
-            transport=transport, fuse_apply=fuse_apply)
+        def step(g, tstate):
+            g2, live, metrics = _superstep(
+                g, tstate, vprog=vprog, send_msg=send_msg, gather=gather,
+                default_msg=default_msg, skip_stale=skip_stale,
+                changed_fn=changed_fn, kernel_mode=kernel_mode,
+                use_cache=incremental, payload_bound=payload_bound,
+                transport=transport, fuse_apply=fuse_apply)
+            return g2, g.ex.psum(live), metrics_across(metrics, g.ex)
+        return per_partition(step, (PARTS, WHOLE, WHOLE))(g, tstate)
     return jax.jit(pregel_superstep, static_argnames=("transport",))
 
 
@@ -463,7 +486,12 @@ def pregel_fused(
     static capacities — an "auto" plan here keeps the policy's static
     capacity and switches dense<->ragged per superstep through the traced
     hysteresis `lax.cond` (the previous decision rides the loop carry).
+    Not supported on a placed graph.
     """
+    if g.mesh is not None:
+        raise NotImplementedError(
+            "pregel_fused on a placed graph: run pregel, whose step runs "
+            "per partition")
     part = functools.partial(
         _superstep, vprog=vprog, send_msg=send_msg, gather=gather,
         default_msg=default_msg, skip_stale=skip_stale,

@@ -46,14 +46,17 @@ def span(name: str, /, **args):
 
 def algorithm(fn):
     """Run a public algorithm as one job under a `graphx.algorithm` span
-    named after it.  An algorithm called from inside another's job joins
-    that job."""
+    named after it, tagged with the devices its graph (the first argument)
+    lives on.  An algorithm called from inside another's job joins that
+    job."""
     @functools.wraps(fn)
     def run(*args, **kwargs):
         outer = _job.get()
         token = _job.set(outer if outer is not None else next(_jobs))
+        devices = getattr(args[0], "num_devices", 1) if args else 1
         try:
-            with span("graphx.algorithm", name=fn.__name__):
+            with span("graphx.algorithm", name=fn.__name__,
+                      devices=devices):
                 return fn(*args, **kwargs)
         finally:
             _job.reset(token)

@@ -459,26 +459,24 @@ def lower_graph_cell_partitioned(*, p: int = 4, partitioner: str = "2d",
     the partitioner sweep materializes a small graph and lowers the exact
     program shard_map deploys.  `bcast_min_repl` enables the §2.1.3
     broadcast lane; the record reports the per-kind collective bytes so
-    callers can assert the lane lowers to a single all-gather."""
-    import dataclasses
+    callers can assert the lane lowers to a single all-gather.  The graph
+    is placed one partition per device (`Graph.place`) and the program is
+    `pregel`'s own jitted step for it: the `supersteps`-th, the ones before
+    it run first (the first ships cold, later ones against a warm view)."""
     from ..core import Graph as GraphCls
     from ..core import algorithms as alg_mod
-    from ..core.exchange import SpmdExchange
-    from ..core.pregel import _superstep
+    from ..core.pregel import superstep_jit
     from ..data import rmat
-    from ..utils.spmd import make_mesh, shard_map as _shard_map
 
-    mesh = make_mesh((p,), ("parts",))
     gd = rmat(scale, edge_factor, seed=seed)
     kw = {} if partitioner == "2d" else {"partitioner": partitioner}
     if bcast_min_repl:
         kw["bcast_min_repl"] = bcast_min_repl
     g = GraphCls.from_edges(gd.src, gd.dst, num_partitions=p, **kw)
+    stats = g.host.stats
+    g = g.place(jax.devices()[:p])
     g = alg_mod.attach_out_degree(g, kernel_mode="ref")
     g = g.mapV(lambda vid, v: {**v, "pr": jnp.float32(1.0)})
-    stats = g.host.stats
-    g = dataclasses.replace(g, ex=SpmdExchange(p=p, axis_name="parts"),
-                            host=None)
 
     def send(sv, ev, dv):
         return {"m": sv["pr"] / sv["deg"] * ev["w"]}
@@ -486,18 +484,15 @@ def lower_graph_cell_partitioned(*, p: int = 4, partitioner: str = "2d",
     def vprog(vid, v, msg):
         return {**v, "pr": 0.15 + 0.85 * msg["m"]}
 
-    def step(gg):
-        out = gg
-        for _ in range(supersteps):
-            out, live, _ = _superstep(
-                out, vprog=vprog, send_msg=send, gather="sum",
-                default_msg={"m": jnp.float32(0.0)}, skip_stale=None,
-                changed_fn=None, kernel_mode="ref", use_cache=True)
-        return out.replace(view=None), live
-
-    fn = jax.jit(_shard_map(step, mesh, (P("parts"),), (P("parts"), P())))
+    step = superstep_jit(vprog, send, "sum",
+                         default_msg={"m": jnp.float32(0.0)},
+                         skip_stale=None, changed_fn=None,
+                         kernel_mode="ref", incremental=True,
+                         payload_bound=None, fuse_apply="auto")
+    for _ in range(supersteps - 1):
+        g, _, _ = step(g)
     t0 = time.time()
-    compiled = fn.lower(g).compile()
+    compiled = step.lower(g).compile()
     compile_s = time.time() - t0
     txt = compiled.as_text()
     coll = hlo_utils.collective_bytes(txt)

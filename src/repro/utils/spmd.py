@@ -1,6 +1,7 @@
-"""SPMD helpers shared by the SPMD test lane (tests/spmd_check.py), the
-benchmark harness (benchmarks/common.py) and chip_smoke.py's 4-chip phase,
-so the mesh and shard_map spelling lives in one place."""
+"""SPMD helpers shared by graph placement (`core.graph.Graph.place`,
+`per_partition`), the SPMD test lane (tests/spmd_check.py) and the
+benchmark harness (benchmarks/common.py), so the mesh and shard_map
+spelling lives in one place."""
 from __future__ import annotations
 
 import jax
