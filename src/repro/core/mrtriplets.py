@@ -790,7 +790,10 @@ def _fused_aggregate(g, mirror_tree, map_fn, live, to, reduce, kernel_mode,
     LOCAL tiling is mapped onto the stacked flat space by `flatten_tiles`
     with the partition's slot space padded to whole vertex blocks, so the
     SAME code serves LocalExchange (nl == P) and shard_map (nl == 1, every
-    device sweeping its own slice of the tables).
+    device sweeping its own slice of the tables).  The tables carry each
+    chunk edge's slots (`rows`), which the kernel reads in place of the
+    flat slots below; only the jnp oracle reads `fsrc`/`fdst`.  The edge
+    payload is packed only where the plan reads it (`plan.e_used`).
 
     `mirror_tree` may hold narrow-RESIDENT leaves (§2.4): when every used
     leaf shares one encoded layout the kernel streams the NARROW payload

@@ -122,7 +122,8 @@ class GraphStructure:
     #   (route_send_idx [P,P,K], route_recv_slot [P,P,K], K)
     routes: dict = None  # type: ignore[assignment]
     # tiles[side] for side in {"dst", "src"}: per-partition chunk tables for
-    # the fused triplet kernel (kernels/triplet.build_triplet_tiles), built
+    # the fused triplet kernel (kernels/triplet.build_triplet_tiles: the
+    # chunk order, block ids and each chunk edge's static slot rows), built
     # once here so they ship to the device as part of StructArrays and shard
     # with the graph — the fused path's §4.3 "index reuse" at kernel level.
     tiles: dict = None  # type: ignore[assignment]
@@ -432,7 +433,8 @@ def build_structure(
     # the tables must already be pytree children when the graph enters
     # shard_map, so there is no later point at which a lazy host build
     # could still reach every device.
-    from ..kernels.triplet import DEFAULT_VERTEX_BLOCK, build_triplet_tiles
+    from ..kernels.triplet import (DEFAULT_VERTEX_BLOCK, build_chunk_tables,
+                                   build_triplet_tiles)
     tiles = {
         "dst": build_triplet_tiles(dst_slot, src_slot, edge_mask, v_mir),
         "src": build_triplet_tiles(src_slot, dst_slot, edge_mask, v_mir),
@@ -455,7 +457,7 @@ def build_structure(
         pe_block = (np.arange(send.shape[1], dtype=np.int32) // k_side
                     * DEFAULT_VERTEX_BLOCK)
         in_slot = np.broadcast_to(pe_block, send.shape)
-        tiles["apply_" + side] = build_triplet_tiles(
+        tiles["apply_" + side] = build_chunk_tables(
             np.maximum(send, 0), in_slot, send >= 0,
             max(v_blk, p * DEFAULT_VERTEX_BLOCK))
 

@@ -55,9 +55,8 @@ def spmv(x, w, src_slot, dst_slot, tiles, active_src_blocks, v_mir: int, *,
     m = _resolve(mode)
     if m == "ref":
         return ref.fused_gather_segment_sum(x, w, src_slot, dst_slot, v_mir)
-    return _spmv.spmv(x, w, src_slot, dst_slot,
-                      tiles["perm"], tiles["chunk_dst"], tiles["chunk_src"],
-                      active_src_blocks, v_mir, eb=eb, vb=vb,
+    return _spmv.spmv(x, w, src_slot, tiles, active_src_blocks, v_mir,
+                      eb=eb, vb=vb,
                       interpret=(m == "interpret"))
 
 
@@ -72,10 +71,12 @@ def triplet(x, ev, src_slot, dst_slot, live, tiles, tile_fn,
             mode: Mode = "auto", eb: int = 512, vb: int = 512):
     """General fused mrTriplets sweep: gather(src,dst) + map + segment-reduce
     in one pass.  `tiles` is the flat device-resident table dict
-    (build_triplet_tiles -> flatten_tiles); the jnp oracle ignores it (pass
-    None).  `xscale` is the narrow-resident scale plane (§2.4): per-32-row
-    E8M0 exponents dequantizing an encoded `x` at the staging seam — in-VMEM
-    on the kernel path, up-front on the oracle, bit-identical either way.
+    (build_triplet_tiles -> flatten_tiles, built toward `to` from these
+    slots); the jnp oracle ignores it (pass None), and the kernel reads the
+    slots from its static `rows` instead of `src_slot`/`dst_slot`.
+    `xscale` is the narrow-resident scale plane (§2.4): per-32-row E8M0
+    exponents dequantizing an encoded `x` at the staging seam — in-VMEM on
+    the kernel path, up-front on the oracle, bit-identical either way.
     Returns (out [S, dm] f32, cnt [S] f32, chunks_live): the kernel's count
     of chunks with a live edge, None on the oracle, which runs no grid."""
     m = _resolve(mode)
@@ -85,7 +86,7 @@ def triplet(x, ev, src_slot, dst_slot, live, tiles, tile_fn,
                                      reduce=reduce)
         return out, cnt, None
     return _triplet.fused_triplet(
-        x, ev, src_slot, dst_slot, live, tiles, tile_fn, num_segments, dm,
+        x, ev, live, tiles, tile_fn, num_segments, dm,
         xscale=xscale, to=to, reduce=reduce, use_src=use_src, use_dst=use_dst,
         eb=eb, vb=vb, interpret=(m == "interpret"))
 

@@ -39,31 +39,21 @@ def build_tiles(
 ) -> dict[str, np.ndarray]:
     """Group edges into Eb-sized chunks sorted by (dst_block, src_block).
 
-    Back-compat FLAT view over the per-partition build_triplet_tiles (dst is
-    the aggregation side; single-partition callers get the identity
-    flattening).
+    The FLAT view of the per-partition build_triplet_tiles (dst is the
+    aggregation side; single-partition callers get the identity
+    flattening): `perm`, `chunk_out`, `chunk_in` and `rows`.
     """
     t = build_triplet_tiles(dst_slot, src_slot, edge_mask, v_mir, eb=eb, vb=vb)
     flat = flatten_tiles(t, e_blk=int(np.asarray(dst_slot).shape[-1]),
                          n_vb=max(-(-v_mir // vb), 1))
-    return dict(
-        perm=np.asarray(flat["perm"]),
-        chunk_dst=np.asarray(flat["chunk_out"]),
-        chunk_src=np.asarray(flat["chunk_in"]),
-        eb=np.int32(eb),
-        vb=np.int32(vb),
-        n_dst_blocks=np.int32(max(-(-v_mir // vb), 1)),
-    )
+    return {k: np.asarray(v) for k, v in flat.items()}
 
 
 def spmv(
     x: jnp.ndarray,           # [V_mir, D] mirror values
     w: jnp.ndarray,           # [E] edge weights (0 for masked edges)
-    src_slot: jnp.ndarray,    # [E] int32
-    dst_slot: jnp.ndarray,    # [E] int32
-    perm: jnp.ndarray,        # [n_chunks*eb] from build_tiles
-    chunk_dst: jnp.ndarray,   # [n_chunks]
-    chunk_src: jnp.ndarray,   # [n_chunks]
+    src_slot: jnp.ndarray,    # [E] int32, the slots build_tiles was given
+    tiles: dict,              # from build_tiles
     active_src_blocks: jnp.ndarray | None,  # [n_src_blocks] bool or None
     v_mir: int,
     *,
@@ -77,9 +67,8 @@ def spmv(
         live = jnp.ones((e,), bool)
     else:                                            # skipStale at block level
         live = active_src_blocks[src_slot // vb]
-    tiles = {"perm": perm, "chunk_out": chunk_dst, "chunk_in": chunk_src}
     out, _, _ = fused_triplet(
-        x, w[:, None], src_slot, dst_slot, live, tiles, _linear_message,
+        x, w[:, None], live, tiles, _linear_message,
         v_mir, x.shape[1], to="dst", reduce="sum", use_dst=False,
         eb=eb, vb=vb, interpret=interpret)
     return out

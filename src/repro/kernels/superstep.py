@@ -20,7 +20,7 @@ the apply, and the changed mask is derived from exactly the values written
 Route entries play the role edges play in the triplet kernel: the apply tile
 tables (partition.build_structure, tiles["apply_*"]) group each partition's
 [P·K] aggregate-return rows into eb-chunks by destination home block through
-the same `build_triplet_tiles` machinery, so chunk skipping, scalar-prefetch
+the same `build_chunk_tables` machinery, so chunk skipping, scalar-prefetch
 indirection, and the scan-sortedness invariant all carry over unchanged.
 
 `apply_fn` is an engine-built closure (core/mrtriplets._make_apply_fn) that
@@ -106,7 +106,7 @@ def fused_apply(
     payload: jnp.ndarray,     # [R, Dm] routed aggregate rows (flat space)
     slot: jnp.ndarray,        # [R] int32 home slot per row (flat PADDED space)
     live: jnp.ndarray,        # [R] bool — row carries a real aggregate
-    tiles: dict,              # FLAT apply tables (build_triplet_tiles over the
+    tiles: dict,              # FLAT apply tables (build_chunk_tables over the
                               # route -> flatten_tiles; in_slot unused)
     x: jnp.ndarray,           # [S, Dv] packed home vertex state
     vid: jnp.ndarray,         # [S] int32 home vertex ids

@@ -19,7 +19,10 @@ Edges are re-sorted at build time into fixed-size chunks grouped by
 aggregation-side tile and one gather-side tile; per-chunk scalars arrive via
 scalar prefetch and *indirect* both vertex BlockSpecs (the Pallas analog of
 GraphX's routing-table join-site lookup).  The same mirror matrix is passed
-twice with different index maps, once per endpoint role.
+twice with different index maps, once per endpoint role.  The chunk-ordered
+edge slots are part of those build-time tables (`rows`, both of an edge's
+slots in one int32); a call gathers only what changes between calls, the
+live bits and the edge payload, through the chunk order in one gather.
 
 §4.6-style index scan: chunks with no live edge are skipped via `pl.when`
 on a per-chunk any-live flag.  `live` is per-EDGE, so the skipping is a pure
@@ -62,11 +65,16 @@ DEFAULT_VERTEX_BLOCK = 512
 SCALE_GROUP = 32
 
 
+# A static edge row holds the aggregation-side slot in its high half-word and
+# the gather-side slot in its low one, both relative to their chunk's block.
+ROW_SHIFT = 16
+
+
 # ----------------------------------------------------------------------------
 # Build-time tiling metadata (numpy; structure is immutable so this runs once
 # per (graph, aggregation side) at `build_structure` time).
 # ----------------------------------------------------------------------------
-def build_triplet_tiles(
+def build_chunk_tables(
     out_slot: np.ndarray,     # [P, E_blk] (or [E]) aggregation-side slots
     in_slot: np.ndarray,      # [P, E_blk] (or [E]) gather-side slots
     edge_mask: np.ndarray,    # [P, E_blk] (or [E]) structural validity
@@ -149,6 +157,44 @@ def build_triplet_tiles(
     return dict(perm=perm, chunk_out=chunk_out, chunk_in=chunk_in)
 
 
+def build_triplet_tiles(
+    out_slot: np.ndarray,     # [P, E_blk] (or [E]) aggregation-side slots
+    in_slot: np.ndarray,      # [P, E_blk] (or [E]) gather-side slots
+    edge_mask: np.ndarray,    # [P, E_blk] (or [E]) structural validity
+    num_slots: int,           # LOCAL slot space size (v_mir), both sides
+    *,
+    eb: int = DEFAULT_EDGE_BLOCK,
+    vb: int = DEFAULT_VERTEX_BLOCK,
+) -> dict[str, np.ndarray]:
+    """The triplet sweep's tables: `build_chunk_tables` and the static half
+    of the sweep's chunk-ordered streams, fixed for the structure, so that a
+    call reads them instead of gathering the slots through `perm`:
+
+      rows       [P, n_chunks, eb]  int32, each chunk edge's aggregation-
+                                    side slot less its chunk's `chunk_out`
+                                    block base, << ROW_SHIFT, | its
+                                    gather-side slot less the `chunk_in`
+                                    base; vb in both halves at padding
+    """
+    if not 0 < vb < 1 << (ROW_SHIFT - 1):
+        raise ValueError(f"vb={vb} does not fit a half of a static edge row")
+    t = build_chunk_tables(out_slot, in_slot, edge_mask, num_slots,
+                           eb=eb, vb=vb)
+    perm = t["perm"]
+    p, e_blk = np.atleast_2d(np.asarray(out_slot)).shape
+    flat = perm.reshape(p, -1)
+    pad = perm >= e_blk
+    rel = []
+    for slot, block in ((out_slot, t["chunk_out"]), (in_slot, t["chunk_in"])):
+        slot = np.atleast_2d(np.asarray(slot)).astype(np.int32)
+        slot = np.concatenate([slot, np.zeros((p, 1), np.int32)], axis=1)
+        r = (np.take_along_axis(slot, flat, axis=1).reshape(perm.shape)
+             - block[:, :, None] * vb)
+        rel.append(np.where(pad, vb, r))
+    t["rows"] = (rel[0] << ROW_SHIFT | rel[1]).astype(np.int32)
+    return t
+
+
 def chunk_live_flags(tiles, live: jnp.ndarray, *, e_blk: int) -> jnp.ndarray:
     """Per-chunk any-live flags [P, n_chunks] for a per-edge live mask
     [P, E_blk] — exactly the `act` bits `fused_triplet` derives to drive
@@ -173,17 +219,24 @@ def flatten_tiles(tiles, *, e_blk: int, n_vb: int) -> dict:
     """Map per-partition [P, n_chunks, ...] tile tables onto the kernel's
     flat stacked space: edge i of partition q -> q*e_blk + i, local block b
     of partition q -> q*n_vb + b (the caller pads each partition's slot
-    space to n_vb*vb slots).  Pure jnp on device arrays — traced, so it runs
-    on each device's OWN [1, ...] slice inside `shard_map`."""
+    space to n_vb*vb slots).  The block-relative `rows`, where the tables
+    have them, need no offset: a flat slot minus its flat block base is the
+    local slot minus the local base, since each partition spans exactly
+    n_vb*vb slots; each chunk's row becomes a [1, eb] block.  Pure jnp on
+    device arrays — traced, so it runs on each device's OWN [1, ...] slice
+    inside `shard_map`."""
     perm = jnp.asarray(tiles["perm"])
     p, n_chunks, eb = perm.shape
     off_e = (jnp.arange(p, dtype=jnp.int32) * e_blk).reshape(p, 1, 1)
     flat_perm = jnp.where(perm >= e_blk, p * e_blk, perm + off_e)
     off_b = (jnp.arange(p, dtype=jnp.int32) * n_vb).reshape(p, 1)
-    return dict(
+    flat = dict(
         perm=flat_perm.reshape(p * n_chunks * eb),
         chunk_out=(jnp.asarray(tiles["chunk_out"]) + off_b).reshape(-1),
         chunk_in=(jnp.asarray(tiles["chunk_in"]) + off_b).reshape(-1))
+    if "rows" in tiles:
+        flat["rows"] = jnp.asarray(tiles["rows"]).reshape(p * n_chunks, 1, eb)
+    return flat
 
 
 def grid_chunk_out(chunk_out: jnp.ndarray, pad: jnp.ndarray) -> jnp.ndarray:
@@ -284,11 +337,11 @@ def _gather(x_ref, sc_ref, slot, cols):
     return v
 
 
-def _make_kernel(tile_fn: Callable, reduce: str, use_src: bool,
+def _make_kernel(tile_fn: Callable, reduce: str, to: str, use_src: bool,
                  use_dst: bool, have_scale: bool):
     ident = REDUCE_IDENTITY[reduce]
 
-    def kernel(cout_ref, csrc_ref, cdst_ref, act_ref, edge_ref, ev_ref,
+    def kernel(cout_ref, csrc_ref, cdst_ref, act_ref, slot_ref, edge_ref,
                *refs):
         refs = list(refs)
         xs_ref = refs.pop(0) if use_src else None
@@ -312,22 +365,29 @@ def _make_kernel(tile_fn: Callable, reduce: str, use_src: bool,
         @pl.when(act_ref[c] != 0)
         def _accumulate():
             vb = out_ref.shape[0]
-            row = edge_ref[...]         # [4, Eb] src / dst / out slot, live
-            col = row.T                                          # [Eb, 4]
+            row = slot_ref[...]         # [1, Eb] static edge rows
+            col = row.T                                          # [Eb, 1]
+            out_row = row >> ROW_SHIFT                           # [1, Eb]
+            out_col = col >> ROW_SHIFT           # aggregation-side slot
+            in_col = col & ((1 << ROW_SHIFT) - 1)    # gather-side slot
+            dyn = edge_ref[...]         # [1+De, Eb] live, then the payload
+            dcol = dyn.T                                         # [Eb, 1+De]
             eb = col.shape[0]
-            live = col[:, 3:4] > 0                               # [Eb, 1]
+            live = dcol[:, 0:1] > 0                              # [Eb, 1]
+            src_col = out_col if to == "src" else in_col
+            dst_col = out_col if to == "dst" else in_col
             cols = jax.lax.broadcasted_iota(jnp.int32, (eb, vb), 1)
             zero = jnp.zeros((eb, 1), jnp.float32)
-            sv = (_gather(xs_ref, ss_ref, col[:, 0:1], cols)
+            sv = (_gather(xs_ref, ss_ref, src_col, cols)
                   if use_src else zero)                          # [Eb, Dx]
-            dv = (_gather(xd_ref, ds_ref, col[:, 1:2], cols)
+            dv = (_gather(xd_ref, ds_ref, dst_col, cols)
                   if use_dst else zero)
-            ev = ev_ref[...].astype(jnp.float32).T               # [Eb, De]
+            ev = dcol[:, 1:]                                     # [Eb, De]
             msgs = tile_fn(sv, ev, dv)                           # [Eb, Dm]
 
             rows = jax.lax.broadcasted_iota(jnp.int32, (vb, eb), 0)
-            oh_t = (row[2:3, :] == rows).astype(jnp.float32)     # [Vb, Eb]
-            oh_live = oh_t * (row[3:4, :] > 0).astype(jnp.float32)
+            oh_t = (out_row == rows).astype(jnp.float32)         # [Vb, Eb]
+            oh_live = oh_t * (dyn[0:1, :] > 0).astype(jnp.float32)
             cnt_ref[...] += jnp.sum(oh_live, axis=1, keepdims=True)
             if reduce == "sum":
                 # dead rows (padding / masked / stale) gathered ZERO endpoint
@@ -342,7 +402,7 @@ def _make_kernel(tile_fn: Callable, reduce: str, use_src: bool,
                 # they never perturb a segment's min/max; padding rows (slot
                 # == vb) match no row of the one-hot.
                 vals = jnp.where(live, msgs, ident)
-                red = segmented_reduce_mxu(vals, col[:, 2:3], row[2:3, :],
+                red = segmented_reduce_mxu(vals, out_col, out_row,
                                            reduce, ident, oh_t)
                 out_ref[...] = sel(out_ref[...], red)            # [Vb, Dm]
 
@@ -356,12 +416,11 @@ def _make_kernel(tile_fn: Callable, reduce: str, use_src: bool,
 def fused_triplet(
     x: jnp.ndarray,           # [S, Dx] packed mirror matrix (any float dtype,
                               # or the encoded payload dtype when xscale set)
-    ev: jnp.ndarray,          # [E, De] packed edge payload
-    src_slot: jnp.ndarray,    # [E] int32 in [0, S)
-    dst_slot: jnp.ndarray,    # [E] int32 in [0, S)
+    ev: jnp.ndarray,          # [E, De] packed edge payload (De may be 0)
     live: jnp.ndarray,        # [E] bool — edge contributes a message
     tiles: dict,              # FLAT tables over the stacked slot/edge space:
-                              # build_triplet_tiles(...) -> flatten_tiles(...)
+                              # build_triplet_tiles(...) -> flatten_tiles(...),
+                              # built toward `to`
     tile_fn: Callable,        # ([Eb,Dx],[Eb,De],[Eb,Dx]) -> [Eb,Dm] f32
     num_segments: int,        # = S
     dm: int,                  # message width
@@ -379,10 +438,15 @@ def fused_triplet(
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """out[v] = reduce_{live e: out(e)=v} tile_fn(x[src(e)], ev[e], x[dst(e)])
 
+    Each edge's endpoints are the slots its tile tables were built from
+    (`rows`, with `to` naming the aggregation side): the slot streams are
+    static, and only the live bits and the payload are gathered per call.
+
     use_src / use_dst: whether tile_fn reads that endpoint's values.  An
     unused side streams nothing: tile_fn receives a width-1 zero column in
     its place (PageRank reads only src) and must not touch it (the engine's
-    side-aware unpack guarantees this).
+    side-aware unpack guarantees this).  A payload of width 0 reaches
+    tile_fn as a width-1 zero column.
 
     The grid is 1-D over the flat chunks, one step each.  A step's output
     block is its chunk's `chunk_out`, resident in VMEM while consecutive
@@ -394,11 +458,11 @@ def fused_triplet(
              chunks_live int32 — chunks with a live edge, the grid steps
              that do work out of n_chunks).
     """
-    e = src_slot.shape[0]
-    de = max(ev.shape[1], 1)
+    e = live.shape[0]
     perm = jnp.asarray(tiles["perm"])
     chunk_out = jnp.asarray(tiles["chunk_out"])
     chunk_in = jnp.asarray(tiles["chunk_in"])
+    slot_rows = jnp.asarray(tiles["rows"])            # [n_chunks, 1, eb]
     n_chunks = chunk_out.shape[0]
     n_vb = max(-(-num_segments // vb), 1)
     v_pad = n_vb * vb
@@ -426,42 +490,36 @@ def fused_triplet(
                       ((0, n_vb * sb - xscale.shape[0]),
                        (0, max(1 - xscale.shape[1], 0))))
         scp = scp.reshape(n_vb, sb, scp.shape[1])
-    # the chunk-ordered streams the grid reads: gathers through `perm`,
-    # named so that a device trace can tell them from the kernel
+    # the chunk-ordered dynamic stream the grid reads: ONE gather through
+    # `perm` of the live bit and the payload columns, staged in f32 (the
+    # kernel reads the payload as f32, so the staging is exact), with a zero
+    # payload column where there is none and a zero row at index e for the
+    # padding slots.  Named so that a device trace can tell it from the
+    # kernel.
     with jax.named_scope("graphx.triplet_streams"):
-        evp = jnp.concatenate(
-            [ev.reshape(e, -1), jnp.zeros((1, ev.shape[1]), ev.dtype)])
-        if ev.shape[1] == 0:
-            evp = jnp.zeros((e + 1, 1), jnp.float32)
-        zero = jnp.zeros((1,), jnp.int32)
-        sp = jnp.concatenate([src_slot.astype(jnp.int32), zero])
-        dp = jnp.concatenate([dst_slot.astype(jnp.int32), zero])
-        lp = jnp.concatenate([live, jnp.zeros((1,), bool)])
-
-        # endpoint roles resolved from the grouping
-        chunk_src = chunk_out if to == "src" else chunk_in
-        chunk_dst = chunk_out if to == "dst" else chunk_in
-        pc = perm.reshape(n_chunks, eb)
-        oob = pc >= e
-        cs = jnp.where(oob, vb, sp[perm].reshape(n_chunks, eb)
-                       - (chunk_src * vb)[:, None]).astype(jnp.int32)
-        cd = jnp.where(oob, vb, dp[perm].reshape(n_chunks, eb)
-                       - (chunk_dst * vb)[:, None]).astype(jnp.int32)
-        co = cs if to == "src" else cd
-        clive = lp[perm].reshape(n_chunks, eb) & ~oob
-        cedge = chunk_rows([cs, cd, co], clive)
-        cev = jnp.swapaxes(evp[perm].reshape(n_chunks, eb, de), 1, 2)
-        act = clive.any(axis=1).astype(jnp.int32)  # chunk skip flag (dynamic)
+        packed = jnp.concatenate([live.astype(jnp.float32)[:, None],
+                                  ev.reshape(e, -1).astype(jnp.float32)],
+                                 axis=1)
+        packed = jnp.pad(packed, ((0, 1), (0, max(2 - packed.shape[1], 0))))
+        width = packed.shape[1]
+        cedge = jnp.swapaxes(packed[perm].reshape(n_chunks, eb, width), 1, 2)
+        act = (cedge[:, 0, :] > 0).any(axis=1).astype(jnp.int32)  # chunk skip
         # grid steps that do work: the live chunks, one grid step each
         chunks_live = act.sum()
 
-    cout = grid_chunk_out(chunk_out, oob.all(axis=1))
+    # endpoint roles resolved from the grouping
+    chunk_src = chunk_out if to == "src" else chunk_in
+    chunk_dst = chunk_out if to == "dst" else chunk_in
+    # a chunk's edges fill it from the front, so a chunk whose first slot is
+    # out of bounds is padding throughout
+    pad = perm.reshape(n_chunks, eb)[:, 0] >= e
+    cout = grid_chunk_out(chunk_out, pad)
     sq = pl.Squeezed()
     in_specs = [
-        pl.BlockSpec((sq, 4, eb), lambda c, co_, cs_, cd_, a: (c, 0, 0)),
-        pl.BlockSpec((sq, de, eb), lambda c, co_, cs_, cd_, a: (c, 0, 0)),
+        pl.BlockSpec((sq, 1, eb), lambda c, co_, cs_, cd_, a: (c, 0, 0)),
+        pl.BlockSpec((sq, width, eb), lambda c, co_, cs_, cd_, a: (c, 0, 0)),
     ]
-    operands = [cedge, cev]
+    operands = [slot_rows, cedge]
     if use_src:
         in_specs.append(pl.BlockSpec(
             (vb, dx), lambda c, co_, cs_, cd_, a: (cs_[c], 0)))
@@ -489,7 +547,7 @@ def fused_triplet(
         ],
     )
     out, cnt = pl.pallas_call(
-        _make_kernel(tile_fn, reduce, use_src, use_dst, have_scale),
+        _make_kernel(tile_fn, reduce, to, use_src, use_dst, have_scale),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((v_pad, dm), jnp.float32),
                    jax.ShapeDtypeStruct((v_pad, 1), jnp.float32)],

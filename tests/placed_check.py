@@ -8,7 +8,8 @@ parent test asserts on, computed here.
 
   * `algorithms.pagerank`, `connected_components` and `sssp` on the placed
     graph against their numpy references and against the same graph
-    unplaced (LocalExchange on one device);
+    unplaced (LocalExchange on one device); PageRank and WCC again through
+    the Pallas sweep in interpret mode, placed against unplaced;
   * where the partitions live, the collectives in the step's program, and
     how many programs the jitted step compiled;
   * the spans and counters of a traced run: `graphx.place`, the `devices`
@@ -136,6 +137,26 @@ def main():
         for k, v in visible(g, s1, "dist").items())
     out["sssp_supersteps"] = s1.supersteps
     out["sssp_depth"] = max(dist.values())
+
+    # ---- the Pallas sweep (interpret mode) on each device's own rows ------
+    # the tile tables' static slot rows shard with the placed graph, so
+    # each device sweeps its own [1, n_chunks, eb] slice
+    sg, sp = g, pg
+    out["rows_shard_shape"] = sorted(
+        {tuple(s.data.shape) for s in
+         sp.s.tiles["dst"]["rows"].addressable_shards})
+    out["rows_full_shape"] = list(sg.s.tiles["dst"]["rows"].shape)
+    k0 = alg.pagerank(sg, num_iters=3, kernel_mode="interpret")
+    k1 = alg.pagerank(sp, num_iters=3, kernel_mode="interpret")
+    a, b = visible(sg, k0, "pr"), visible(sg, k1, "pr")
+    out["kernel_pr_rel_err_unplaced"] = max(abs(v - a[k]) / abs(a[k])
+                                            for k, v in b.items())
+    w0 = alg.connected_components(sg, kernel_mode="interpret")
+    w1 = alg.connected_components(sp, kernel_mode="interpret")
+    out["kernel_cc_mismatches_unplaced"] = sum(
+        int(v) != int(u) for v, u in zip(
+            visible(sg, w0, "cc").values(), visible(sg, w1, "cc").values()))
+    out["kernel_cc_supersteps"] = [w0.supersteps, w1.supersteps]
 
     # ---- spans and counters of a traced run ------------------------------
     logdir = tempfile.mkdtemp()

@@ -65,3 +65,47 @@ def test_collective_bytes_empty_for_local_program():
     import jax.numpy as jnp
     txt = _lower_text(lambda x: x * 2, jnp.ones((16,), jnp.float32))
     assert hlo.collective_bytes(txt)["total_bytes"] == 0
+
+
+def _perm_gathers(hlo_text: str, n_idx: int) -> int:
+    """Gathers in an HLO module that read `n_idx` indices: the product of
+    the output dimensions that are not the gathered slice's own."""
+    import re
+    count = 0
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* gather\([^)]*\), "
+                         r"offset_dims=\{([\d,]*)\}", hlo_text):
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        offset = {int(d) for d in m.group(2).split(",") if d}
+        batch = [d for i, d in enumerate(dims) if i not in offset]
+        count += int(np.prod(batch)) == n_idx
+    return count
+
+
+@pytest.mark.parametrize("payload", [1, 0])
+def test_fused_sweep_gathers_through_perm_once(payload):
+    """The fused triplet sweep makes one gather of n_chunks·eb indices, of
+    the live bits and the edge payload, or of the live bits alone where the
+    payload has no column; its slot rows come from the tile tables."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import triplet
+
+    p, e_blk, v_mir, eb, vb = 2, 300, 64, 32, 16
+    rng = np.random.default_rng(0)
+    tiles = triplet.build_triplet_tiles(
+        rng.integers(0, v_mir, (p, e_blk)), rng.integers(0, v_mir, (p, e_blk)),
+        np.ones((p, e_blk), bool), v_mir, eb=eb, vb=vb)
+    flat = triplet.flatten_tiles(tiles, e_blk=e_blk, n_vb=v_mir // vb)
+    n_idx = flat["perm"].size
+    assert n_idx == flat["chunk_out"].size * eb > eb
+    e = p * e_blk
+
+    def sweep(x, ev, live, t):
+        return triplet.fused_triplet(
+            x, ev, live, t, lambda sv, ev, dv: sv[:, :1], p * v_mir, 1,
+            use_dst=False, eb=eb, vb=vb, interpret=True)
+
+    txt = jax.jit(sweep).lower(
+        jnp.ones((p * v_mir, 2)), jnp.ones((e, payload)),
+        jnp.ones((e,), bool), flat).as_text(dialect="hlo")
+    assert _perm_gathers(txt, n_idx) == 1

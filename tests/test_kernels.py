@@ -52,9 +52,9 @@ def test_triplet_kernel_matches_oracle(reduce, to, e, v, dx, eb, vb):
     out_s, in_s = (dst, src) if to == "dst" else (src, dst)
     tiles = _flat_tiles(out_s, in_s, np.ones(e, bool), v, eb=eb, vb=vb)
     got, cnt, chunks_live = triplet_mod.fused_triplet(
-        jnp.asarray(x), jnp.asarray(ev), jnp.asarray(src), jnp.asarray(dst),
-        jnp.asarray(live), tiles, _affine_msg, v, dx, to=to, reduce=reduce,
-        eb=eb, vb=vb, interpret=True)
+        jnp.asarray(x), jnp.asarray(ev), jnp.asarray(live), tiles,
+        _affine_msg, v, dx, to=to, reduce=reduce, eb=eb, vb=vb,
+        interpret=True)
     want, cnt_want = ref.fused_triplet(
         jnp.asarray(x), jnp.asarray(ev), jnp.asarray(src), jnp.asarray(dst),
         jnp.asarray(live), _affine_msg, v, to=to, reduce=reduce)
@@ -73,9 +73,8 @@ def test_triplet_kernel_dead_edges_and_empty_segments():
     tiles = _flat_tiles(dst, src, np.ones(e, bool), v, eb=32, vb=16)
     for reduce in ("sum", "min", "max"):
         out, cnt, chunks_live = triplet_mod.fused_triplet(
-            jnp.asarray(x), jnp.asarray(ev), jnp.asarray(src),
-            jnp.asarray(dst), jnp.asarray(live), tiles, _affine_msg, v, 2,
-            reduce=reduce, eb=32, vb=16, interpret=True)
+            jnp.asarray(x), jnp.asarray(ev), jnp.asarray(live), tiles,
+            _affine_msg, v, 2, reduce=reduce, eb=32, vb=16, interpret=True)
         assert float(np.asarray(cnt).sum()) == 0.0
         assert int(chunks_live) == 0              # every chunk skipped
         ident = triplet_mod.REDUCE_IDENTITY[reduce]
@@ -113,10 +112,10 @@ def test_triplet_tiles_per_partition_flatten():
     msg = lambda sv, evv, dv: sv * evv[:, :1] + dv
     for reduce in ("sum", "min"):
         got, cnt, _ = triplet_mod.fused_triplet(
-            jnp.asarray(xpad.reshape(p * v_pad, dx)), jnp.asarray(ev.reshape(-1, 1)),
-            jnp.asarray((src + off).reshape(-1)), jnp.asarray((dst + off).reshape(-1)),
-            jnp.asarray(live.reshape(-1)), flat, msg, p * v_pad, dx,
-            reduce=reduce, eb=eb, vb=vb, interpret=True)
+            jnp.asarray(xpad.reshape(p * v_pad, dx)),
+            jnp.asarray(ev.reshape(-1, 1)), jnp.asarray(live.reshape(-1)),
+            flat, msg, p * v_pad, dx, reduce=reduce, eb=eb, vb=vb,
+            interpret=True)
         got = np.asarray(got).reshape(p, v_pad, dx)[:, :v_mir]
         cnt = np.asarray(cnt).reshape(p, v_pad)[:, :v_mir]
         for q in range(p):   # each partition == its own single-device sweep
@@ -179,8 +178,8 @@ def test_triplet_chunk_grid_block_order(reduce, to):
     k = _grid_order_case(to)
     args = [jnp.asarray(k[n]) for n in ("x", "ev", "src", "dst", "live")]
     got, cnt, _ = triplet_mod.fused_triplet(
-        *args, k["tiles"], _affine_msg, k["v"], k["dx"], to=to,
-        reduce=reduce, eb=k["eb"], vb=k["vb"], interpret=True)
+        args[0], args[1], args[4], k["tiles"], _affine_msg, k["v"], k["dx"],
+        to=to, reduce=reduce, eb=k["eb"], vb=k["vb"], interpret=True)
     want, cnt_want = ref.fused_triplet(*args, _affine_msg, k["v"], to=to,
                                        reduce=reduce)
     np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_want))
@@ -221,6 +220,129 @@ def test_grid_chunk_out_never_decreases(source):
     assert (np.diff(co) < 0).any()        # the stored tables break the order
     assert (np.diff(cout) >= 0).all()
     np.testing.assert_array_equal(cout[~pad], co[~pad])
+
+
+@pytest.mark.parametrize("p", [1, 4])
+@pytest.mark.parametrize("to", ["dst", "src"])
+def test_triplet_tile_rows_match_per_call_gather(to, p):
+    """The static `rows` of the tile tables equal what the sweep used to
+    gather through `perm` on every call: each chunk edge's aggregation-side
+    and gather-side slot in the flat space, less its chunk's block base,
+    and vb on padding, packed in the high and the low half-word.  With p == 4, partition 1 has no edge and partition 3
+    few, so both end in whole padding chunks."""
+    e_blk, v_mir, eb, vb = 150, 80, 32, 16
+    n_vb = -(-v_mir // vb)
+    rng = np.random.default_rng(13)
+    src = rng.integers(0, v_mir, (p, e_blk)).astype(np.int32)
+    dst = rng.integers(0, v_mir, (p, e_blk)).astype(np.int32)
+    mask = rng.random((p, e_blk)) > 0.1
+    if p == 4:
+        mask[1] = False
+        mask[3, 12:] = False
+    out_s, in_s = (dst, src) if to == "dst" else (src, dst)
+    tiles = triplet_mod.build_triplet_tiles(out_s, in_s, mask, v_mir,
+                                            eb=eb, vb=vb)
+    n_chunks = tiles["chunk_out"].shape[1]
+    assert tiles["rows"].shape == (p, n_chunks, eb)
+    assert tiles["rows"].dtype == np.int32
+    # the apply tables are built without them
+    assert "rows" not in triplet_mod.build_chunk_tables(
+        out_s, in_s, mask, v_mir, eb=eb, vb=vb)
+    flat = triplet_mod.flatten_tiles(tiles, e_blk=e_blk, n_vb=n_vb)
+
+    # the per-call derivation the rows replace, on the flat space
+    e = p * e_blk
+    off = (np.arange(p) * n_vb * vb)[:, None]
+    sp = np.append((src + off).reshape(-1), 0)
+    dp = np.append((dst + off).reshape(-1), 0)
+    perm = np.asarray(flat["perm"]).reshape(-1, eb)
+    co, ci = np.asarray(flat["chunk_out"]), np.asarray(flat["chunk_in"])
+    chunk_src = co if to == "src" else ci
+    chunk_dst = co if to == "dst" else ci
+    oob = perm >= e
+    cs = np.where(oob, vb, sp[perm] - (chunk_src * vb)[:, None])
+    cd = np.where(oob, vb, dp[perm] - (chunk_dst * vb)[:, None])
+    rows = np.asarray(flat["rows"])
+    assert rows.shape == (p * n_chunks, 1, eb)
+    shift = triplet_mod.ROW_SHIFT
+    np.testing.assert_array_equal(rows[:, 0] >> shift,
+                                  cs if to == "src" else cd)
+    np.testing.assert_array_equal(rows[:, 0] & ((1 << shift) - 1),
+                                  cd if to == "src" else cs)
+    assert oob.any(axis=1).all()            # every chunk is part padding
+    if p == 4:                              # whole padding chunks
+        assert oob.all(axis=1).sum() >= n_chunks + 1
+        assert (rows[oob.all(axis=1)] == vb << shift | vb).all()
+
+
+def _sweep_msg(use_src, use_dst, payload):
+    """A message that reads exactly the sides the sweep streams."""
+    def msg(sv, ev, dv):
+        m = jnp.zeros((sv.shape[0], 1), jnp.float32)
+        if use_src:
+            m = m + sv[:, :1] * (ev[:, :1] if payload else 1.0)
+        if use_dst:
+            m = m + dv[:, 1:2] - (ev[:, 1:2] if payload else 0.0)
+        return m
+    return msg
+
+
+@pytest.mark.parametrize("to", ["dst", "src"])
+@pytest.mark.parametrize("reduce,use_src,use_dst,stage", [
+    ("sum", True, False, "f32"),          # PageRank's shape
+    ("sum", True, True, "f32"),
+    ("min", True, False, "no_payload"),   # WCC's shape
+    ("max", False, True, "f32"),
+    ("min", True, True, "no_payload"),
+    ("sum", True, True, "bf16"),
+    ("max", True, True, "xscale"),
+    ("sum", False, True, "xscale"),
+])
+def test_triplet_sweep_matches_oracle_bit_for_bit(to, reduce, use_src,
+                                                  use_dst, stage):
+    """The sweep on static slot rows and one dynamic gather, in interpret
+    mode, against the oracle: every reduce, every endpoint combination, no
+    edge payload, a bf16 mirror and a narrow-resident mirror with its scale
+    plane; results, counts and live chunks equal bit for bit."""
+    p, e_blk, v_mir, dx = 3, 120, 96, 2
+    eb = vb = 32
+    rng = np.random.default_rng(17)
+    src = rng.integers(0, v_mir, (p, e_blk)).astype(np.int32)
+    dst = rng.integers(0, v_mir, (p, e_blk)).astype(np.int32)
+    mask = rng.random((p, e_blk)) > 0.2
+    mask[1] = False                        # a partition with no edge
+    mask[2, 20:] = False                   # and one ending in padding
+    live = mask & (rng.random((p, e_blk)) > 0.3)
+    out_s, in_s = (dst, src) if to == "dst" else (src, dst)
+    tiles = triplet_mod.build_triplet_tiles(out_s, in_s, mask, v_mir,
+                                            eb=eb, vb=vb)
+    flat = triplet_mod.flatten_tiles(tiles, e_blk=e_blk, n_vb=v_mir // vb)
+    off = (np.arange(p) * v_mir)[:, None]
+    fsrc = jnp.asarray((src + off).reshape(-1))
+    fdst = jnp.asarray((dst + off).reshape(-1))
+    s = p * v_mir
+    x = rng.integers(-4, 5, (s, dx)).astype(np.float32)
+    xscale = None
+    if stage == "bf16":
+        x = jnp.asarray(x, jnp.bfloat16)
+    elif stage == "xscale":
+        x = jnp.asarray(x, jnp.int8)
+        xscale = jnp.asarray(rng.integers(-2, 3, (s // 32, dx)), jnp.int8)
+    payload = stage != "no_payload"
+    ev = rng.integers(1, 4, (p * e_blk, 2 if payload else 0))
+    ev, x = jnp.asarray(ev, jnp.float32), jnp.asarray(x)
+    lv = jnp.asarray(live.reshape(-1))
+    fn = _sweep_msg(use_src, use_dst, payload)
+    got, cnt, chunks_live = triplet_mod.fused_triplet(
+        x, ev, lv, flat, fn, s, 1, xscale=xscale, to=to, reduce=reduce,
+        use_src=use_src, use_dst=use_dst, eb=eb, vb=vb, interpret=True)
+    want, cnt_want = ref.fused_triplet(x, ev, fsrc, fdst, lv, fn, s,
+                                       xscale=xscale, to=to, reduce=reduce)
+    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_want))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    perm = np.asarray(flat["perm"]).reshape(-1, eb)
+    lp = np.append(live.reshape(-1), False)
+    assert int(chunks_live) == int(lp[perm].any(axis=1).sum()) > 0
 
 
 def _build_engine_graph(seed=0, p=4, scale=6, ef=4, payload_dim=0):
@@ -550,10 +672,8 @@ def test_spmv_sweep(e, v, d, eb, vb):
     x = RNG.normal(size=(v, d)).astype(np.float32)
     tiles = spmv_mod.build_tiles(src, dst, mask, v, eb=eb, vb=vb)
     out = spmv_mod.spmv(
-        jnp.asarray(x), jnp.asarray(w), jnp.asarray(src), jnp.asarray(dst),
-        jnp.asarray(tiles["perm"]), jnp.asarray(tiles["chunk_dst"]),
-        jnp.asarray(tiles["chunk_src"]), None, v, eb=eb, vb=vb,
-        interpret=True)
+        jnp.asarray(x), jnp.asarray(w), jnp.asarray(src), tiles, None, v,
+        eb=eb, vb=vb, interpret=True)
     want = ref.fused_gather_segment_sum(
         jnp.asarray(x), jnp.asarray(w), jnp.asarray(src), jnp.asarray(dst), v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -572,10 +692,8 @@ def test_spmv_active_block_skip():
     active = np.zeros(n_src_blocks, bool)
     active[0] = True   # only sources in block 0 are fresh
     out = spmv_mod.spmv(
-        jnp.asarray(x), jnp.asarray(w), jnp.asarray(src), jnp.asarray(dst),
-        jnp.asarray(tiles["perm"]), jnp.asarray(tiles["chunk_dst"]),
-        jnp.asarray(tiles["chunk_src"]), jnp.asarray(active), v,
-        eb=64, vb=32, interpret=True)
+        jnp.asarray(x), jnp.asarray(w), jnp.asarray(src), tiles,
+        jnp.asarray(active), v, eb=64, vb=32, interpret=True)
     w_masked = w * (src < 32)
     want = ref.fused_gather_segment_sum(
         jnp.asarray(x), jnp.asarray(w_masked), jnp.asarray(src),

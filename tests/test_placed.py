@@ -58,6 +58,19 @@ def test_cc_and_sssp_are_exact(report):
     assert report["sssp_supersteps"] == report["sssp_depth"] + 1
 
 
+def test_kernel_sweep_on_placed_rows_matches_unplaced(report):
+    """PageRank and WCC through the Pallas sweep (interpret mode): each
+    device holds its own slice of the static slot rows, and the answers
+    are the unplaced graph's."""
+    p, n_chunks, eb = report["rows_full_shape"]
+    assert report["rows_shard_shape"] == [[1, n_chunks, eb]]
+    assert p == 4 and n_chunks > 1
+    assert report["kernel_pr_rel_err_unplaced"] <= 1e-6
+    assert report["kernel_cc_mismatches_unplaced"] == 0
+    a, b = report["kernel_cc_supersteps"]
+    assert a == b > 1
+
+
 def test_step_exchanges_by_all_to_all_without_retracing(report):
     assert report["step_all_to_all"]
     # the cold first superstep and the warm rest: no program per superstep

@@ -56,7 +56,8 @@ def _specs(sharding):
 def _tiles(spec, n_chunks):
     return {"perm": spec((n_chunks * EB,), jnp.int32),
             "chunk_out": spec((n_chunks,), jnp.int32),
-            "chunk_in": spec((n_chunks,), jnp.int32)}
+            "chunk_in": spec((n_chunks,), jnp.int32),
+            "rows": spec((n_chunks, 1, EB), jnp.int32)}
 
 
 def _compile(fn, *args):
@@ -94,14 +95,13 @@ def test_fused_triplet_compiles_for_v5e(one_chip, case):
                "sum_int8_xscale": _both_msg}[case]
     reduce = "min" if case == "min" else "sum"
 
-    def fn(x, ev, src, dst, live, tiles, xscale):
+    def fn(x, ev, live, tiles, xscale):
         return triplet.fused_triplet(
-            x, ev, src, dst, live, tiles, tile_fn, seg, 1, xscale=xscale,
+            x, ev, live, tiles, tile_fn, seg, 1, xscale=xscale,
             reduce=reduce, use_src=True, use_dst=scaled, eb=EB, vb=VB)
 
-    _compile(fn, x, spec((e, 1), jnp.float32), spec((e,), jnp.int32),
-             spec((e,), jnp.int32), spec((e,), jnp.bool_),
-             _tiles(spec, P * TRIPLET_CHUNKS), xscale)
+    _compile(fn, x, spec((e, 0 if case == "min" else 1), jnp.float32),
+             spec((e,), jnp.bool_), _tiles(spec, P * TRIPLET_CHUNKS), xscale)
 
 
 def _apply_fn(reduce):
