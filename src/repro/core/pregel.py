@@ -23,7 +23,8 @@ Differences from classic Pregel, following the paper:
     "unfused" pins the gather -> vmap -> segment-reduce plan.
 
 Two drivers:
-  * `pregel` — host loop, jitted superstep, per-step metrics (benchmarks);
+  * `pregel` — host loop, jitted superstep, per-step metrics: the public
+    path every algorithm and the chip benchmark (`bench/run.py`) run;
   * `pregel_fused` — single `lax.while_loop` program (the dry-run artifact:
     the whole algorithm lowers to one XLA program on the production mesh).
 """

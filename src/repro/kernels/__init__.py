@@ -3,7 +3,6 @@
 triplet     — general fused mrTriplets sweep: gather(src,dst) + map UDF +
               segment reduce (sum/min/max) in one kernel (DESIGN.md §2.3)
 segment_sum — CSR message aggregation (the unfused mrTriplets reduce)
-spmv        — linear-message SpMV, the degenerate instance of `triplet`
 flash_attention — LM-substrate attention
 
 Each kernel: <name>.py (pl.pallas_call + BlockSpec), wrapped by ops.py,

@@ -5,7 +5,7 @@ Dispatch policy (one switch for the whole engine):
   * on CPU (tests)   -> pure-jnp oracle from ref.py (fast) or the kernel in
                         interpret mode (exact kernel semantics; used by the
                         per-kernel sweep tests),
-  * `force` overrides for benchmarking either path.
+  * a `mode` other than "auto" pins one path (`kernel_mode` passes it).
 """
 from __future__ import annotations
 
@@ -17,7 +17,6 @@ import jax.numpy as jnp
 
 from . import ref
 from . import segment_sum as _segsum
-from . import spmv as _spmv
 from . import triplet as _triplet
 from . import flash_attention as _flash
 
@@ -50,17 +49,6 @@ def segment_sum(msgs, seg_ids, num_segments: int, *, mode: Mode = "auto",
         interpret=(m == "interpret"))
 
 
-def spmv(x, w, src_slot, dst_slot, tiles, active_src_blocks, v_mir: int, *,
-         mode: Mode = "auto", eb: int = 512, vb: int = 512):
-    m = _resolve(mode)
-    if m == "ref":
-        return ref.fused_gather_segment_sum(x, w, src_slot, dst_slot, v_mir)
-    return _spmv.spmv(x, w, src_slot, tiles, active_src_blocks, v_mir,
-                      eb=eb, vb=vb,
-                      interpret=(m == "interpret"))
-
-
-build_tiles = _spmv.build_tiles
 build_triplet_tiles = _triplet.build_triplet_tiles
 flatten_tiles = _triplet.flatten_tiles
 

@@ -23,23 +23,6 @@ def segment_sum(msgs: jnp.ndarray, seg_ids: jnp.ndarray, num_segments: int) -> j
     return out.astype(msgs.dtype)
 
 
-def fused_gather_segment_sum(
-    x: jnp.ndarray,          # [V_mir, D] mirror vertex values
-    w: jnp.ndarray,          # [E] edge weights
-    src_slot: jnp.ndarray,   # [E] int32
-    dst_slot: jnp.ndarray,   # [E] int32 (sorted; padding -> >= num_segments)
-    num_segments: int,
-) -> jnp.ndarray:
-    """Fused triplet-map + aggregate: out[v] = sum_{e: dst=v} w[e] * x[src[e]].
-
-    This is mrTriplets specialised to linear messages (PageRank, degree with
-    w=1, weighted diffusion) — one HBM pass instead of materialising [E, D]
-    messages.  Equivalent to SpMV with a block-CSR matrix.
-    """
-    msgs = x[src_slot] * w[:, None].astype(x.dtype)
-    return segment_sum(msgs, dst_slot, num_segments)
-
-
 # The finite (finfo-extreme) identity convention shared with the kernel —
 # single source of truth so oracle and kernel stay bit-identical on empty
 # segments (triplet.py imports nothing back from this module).

@@ -28,9 +28,6 @@ live bits and the edge payload, through the chunk order in one gather.
 on a per-chunk any-live flag.  `live` is per-EDGE, so the skipping is a pure
 optimisation — results are identical to the unfused path's edge-granular
 skipStale masking, while whole stale tiles cost nothing.
-
-The scalar SpMV kernel (kernels/spmv.py) is the degenerate instance of this
-kernel: linear message, sum reduce, src-only gather.
 """
 from __future__ import annotations
 

@@ -4,8 +4,8 @@ Given a compiled cell, attribute collective bytes and HBM traffic to the
 JAX source operation (HLO metadata op_name), trip-count corrected — a
 static profile read from the compiled program on simulated host devices.
 Nothing here runs on a chip: its times are a model built from the
-constants below, never a device measurement (chip_smoke.py runs on the
-chip).
+constants below, never a device measurement (bench/run.py is the chip
+benchmark).
 
   PYTHONPATH=src python -m repro.launch.perf --arch arctic-480b \
       --shape prefill_32k [--variant seqshard] [--top 15]
@@ -123,41 +123,11 @@ def summarize(rec: dict, txt: str, top: int = 12) -> None:
         print(f"  {b:12.3e}  x{c:<6.0f} {name[:110]}")
 
 
-def summarize_superstep(path: str) -> None:
-    """Print the persisted superstep-fusion trajectory (BENCH_superstep.json,
-    benchmarks/superstep_bench.py) as a roofline table: per cell, the modeled
-    per-superstep time split into HBM (home materializations) and the
-    unhidden link fraction, plus what the ring pipeline hides."""
-    import json
-    with open(path) as f:
-        doc = json.load(f)
-    print(f"=== superstep fusion/overlap trajectory ({path}) ===")
-    print(f" model: HBM {doc['model']['HBM_BW']:.0e} B/s, "
-          f"link {doc['model']['LINK_BW']:.0e} B/s, P={doc['model']['P']}")
-    hdr = (f"{'workload':<16} {'partitioner':<13} {'transport':<9} "
-           f"{'codec':<5} {'pipe':<5} "
-           f"{'B/chip':>9} {'overlap':>7} {'t_step':>10} {'mats f/u':>9}")
-    print(hdr)
-    for r in doc["rows"]:
-        print(f"{r['workload']:<16} {r.get('partitioner', '2d'):<13} "
-              f"{r['transport']:<9} {r['codec']:<5} "
-              f"{str(r['pipeline']):<5} {r['bytes_per_chip']:>9} "
-              f"{r['overlap_efficiency']:>7.2f} "
-              f"{r['step_time_modeled_s']:>10.3e} "
-              f"{r['materializations_fused']:>4}/"
-              f"{r['materializations_unfused']}")
-
-
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
+    ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", default=None)
     ap.add_argument("--graph", action="store_true")
-    ap.add_argument("--superstep", nargs="?", const="BENCH_superstep.json",
-                    default=None, metavar="BENCH_JSON",
-                    help="print the persisted superstep fusion/overlap "
-                         "trajectory and exit (default file: "
-                         "BENCH_superstep.json)")
     ap.add_argument("--strategy", default=None)
     ap.add_argument("--top", type=int, default=12)
     ap.add_argument("--kernel-mode", default="ref")
@@ -199,12 +169,6 @@ def main():
     ap.add_argument("--contrib-form", action="store_true",
                     help="graph cell: ship a precomputed contrib property")
     args = ap.parse_args()
-
-    if args.superstep is not None:
-        summarize_superstep(args.superstep)
-        return
-    if args.arch is None:
-        ap.error("--arch is required (or use --superstep)")
 
     from .mesh import make_production_mesh, make_graph_mesh
     from . import dryrun

@@ -1,9 +1,8 @@
 """JAX's persistent compilation cache, at one fixed place per checkout.
 
-Entry points that compile for the accelerator (`chip_smoke.py`,
-`benchmarks/run.py`) call `enable_compile_cache()` before their first
-compile.  Importing `repro` never turns the cache on, so tests and library
-users keep JAX's defaults.
+The chip benchmark (`bench/run.py`) calls `enable_compile_cache()` before
+its first compile.  Importing `repro` never turns the cache on, so tests
+and library users keep JAX's defaults.
 """
 from __future__ import annotations
 

@@ -1,7 +1,6 @@
 """SPMD helpers shared by graph placement (`core.graph.Graph.place`,
-`per_partition`), the SPMD test lane (tests/spmd_check.py) and the
-benchmark harness (benchmarks/common.py), so the mesh and shard_map
-spelling lives in one place."""
+`per_partition`) and the SPMD test lane (tests/spmd_check.py), so the mesh
+and shard_map spelling lives in one place."""
 from __future__ import annotations
 
 import jax

@@ -13,12 +13,15 @@ def graph_of(gd, p=4, weights=None):
     return Graph.from_edges(gd.src, gd.dst, edge_values=ev, num_partitions=p)
 
 
-@pytest.mark.parametrize("seed,p", [
-    (0, 2), pytest.param(1, 4, marks=pytest.mark.slow),
-    pytest.param(2, 6, marks=pytest.mark.slow)])
-def test_pagerank_matches_reference(seed, p):
+# "interpret" runs the engine's Pallas kernels (interpret mode) through the
+# same public path a chip runs compiled; "auto" picks the jnp oracle on CPU.
+@pytest.mark.parametrize("seed,p,kernel_mode", [
+    (0, 2, "auto"), pytest.param(1, 4, "auto", marks=pytest.mark.slow),
+    pytest.param(2, 6, "auto", marks=pytest.mark.slow),
+    (0, 2, "interpret")], ids=["0-2", "1-4", "2-6", "0-2-interpret"])
+def test_pagerank_matches_reference(seed, p, kernel_mode):
     gd = rmat(6, 4, seed=seed)
-    res = alg.pagerank(graph_of(gd, p), num_iters=15)
+    res = alg.pagerank(graph_of(gd, p), num_iters=15, kernel_mode=kernel_mode)
     vids, vals = res.graph.vertices_to_numpy()
     ref = alg.pagerank_reference(gd.src, gd.dst, gd.num_vertices, 15)
     np.testing.assert_allclose(vals["pr"], ref[vids], rtol=1e-4)
@@ -50,12 +53,14 @@ def test_cc_matches_union_find():
     assert got == want
 
 
-def test_cc_dispatches_fused_and_matches_reference():
+@pytest.mark.parametrize("kernel_mode", ["auto", "interpret"])
+def test_cc_dispatches_fused_and_matches_reference(kernel_mode):
     """The int32 min-label Pregel loop rides the fused triplet kernel end to
     end (f32 staging is exact under the id-bound guard) and agrees with the
     union-find oracle EXACTLY — and with the unfused plan bit-for-bit."""
     gd = symmetrize(rmat(6, 3, seed=17))
-    res = alg.connected_components(graph_of(gd), track_metrics=True)
+    res = alg.connected_components(graph_of(gd), kernel_mode=kernel_mode,
+                                   track_metrics=True)
     assert res.metrics[0]["plan"] == "fused"
     assert res.graph.vdata["cc"].dtype == jnp.int32
     vids, vals = res.graph.vertices_to_numpy()
@@ -72,7 +77,8 @@ def test_cc_dispatches_fused_and_matches_reference():
                                   np.asarray(vals_u["cc"]))
 
 
-def test_sssp():
+@pytest.mark.parametrize("kernel_mode", ["auto", "interpret"])
+def test_sssp(kernel_mode):
     # weighted path 0 -> 1 -> 2 ... with weight 2 each
     n = 12
     src = np.arange(n - 1, dtype=np.int64)
@@ -81,7 +87,7 @@ def test_sssp():
     res = alg.sssp(graph_of(type("G", (), {
         "src": src, "dst": dst, "num_edges": n - 1,
         "num_vertices": n})(), weights=w), source=0,
-        max_supersteps=n + 2)
+        max_supersteps=n + 2, kernel_mode=kernel_mode)
     vids, vals = res.graph.vertices_to_numpy()
     for vid, d in zip(vids, vals["dist"]):
         assert d == 2.0 * vid

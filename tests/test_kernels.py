@@ -6,7 +6,6 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.segment_sum import segment_sum
-from repro.kernels import spmv as spmv_mod
 from repro.kernels import triplet as triplet_mod
 from repro.kernels.flash_attention import flash_attention
 
@@ -661,44 +660,56 @@ def test_segment_sum_empty_segments():
     assert float(np.abs(np.asarray(out)).sum()) == 64.0
 
 
-# ----------------------------------------------------------------------- spmv
+# ------------------------------------------------------- linear message sweep
+def _linear_msg(sv, evv, dv):
+    """msg = w · x[src]: PageRank's message, summed to dst."""
+    return sv * evv[:, :1]
+
+
+def _linear_sweep(x, w, src, dst, mask, live, v, *, eb, vb):
+    tiles = _flat_tiles(dst, src, mask, v, eb=eb, vb=vb)
+    out, _, _ = triplet_mod.fused_triplet(
+        jnp.asarray(x), jnp.asarray(w)[:, None], jnp.asarray(live), tiles,
+        _linear_msg, v, x.shape[1], to="dst", reduce="sum", use_dst=False,
+        eb=eb, vb=vb, interpret=True)
+    return np.asarray(out)
+
+
+def _linear_reference(x, w, src, dst, v):
+    want = np.zeros((v, x.shape[1]), np.float32)
+    np.add.at(want, dst, w[:, None] * x[src])
+    return want
+
+
 @pytest.mark.parametrize("e,v,d,eb,vb", [
     (500, 100, 1, 128, 64), (2000, 500, 8, 256, 128), (64, 16, 4, 32, 16)])
-def test_spmv_sweep(e, v, d, eb, vb):
+def test_linear_message_sweep(e, v, d, eb, vb):
+    """The fused sweep with a linear message is SpMV over the edge set."""
     src = RNG.integers(0, v, e).astype(np.int32)
     dst = RNG.integers(0, v, e).astype(np.int32)
     mask = RNG.random(e) > 0.15
     w = (RNG.normal(size=e) * mask).astype(np.float32)
     x = RNG.normal(size=(v, d)).astype(np.float32)
-    tiles = spmv_mod.build_tiles(src, dst, mask, v, eb=eb, vb=vb)
-    out = spmv_mod.spmv(
-        jnp.asarray(x), jnp.asarray(w), jnp.asarray(src), tiles, None, v,
-        eb=eb, vb=vb, interpret=True)
-    want = ref.fused_gather_segment_sum(
-        jnp.asarray(x), jnp.asarray(w), jnp.asarray(src), jnp.asarray(dst), v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+    out = _linear_sweep(x, w, src, dst, mask, np.ones(e, bool), v,
+                        eb=eb, vb=vb)
+    np.testing.assert_allclose(out, _linear_reference(x, w, src, dst, v),
                                rtol=1e-4, atol=1e-4)
 
 
-def test_spmv_active_block_skip():
-    """skipStale at block level: stale source blocks contribute nothing."""
-    v, e = 128, 400
+def test_linear_message_stale_source_blocks():
+    """skipStale at block level: edges out of stale source blocks are dead
+    and contribute nothing."""
+    v, e, vb = 128, 400, 32
     src = RNG.integers(0, v, e).astype(np.int32)
     dst = RNG.integers(0, v, e).astype(np.int32)
     w = np.ones(e, np.float32)
     x = RNG.normal(size=(v, 2)).astype(np.float32)
-    tiles = spmv_mod.build_tiles(src, dst, np.ones(e, bool), v, eb=64, vb=32)
-    n_src_blocks = -(-v // 32)
-    active = np.zeros(n_src_blocks, bool)
+    active = np.zeros(-(-v // vb), bool)
     active[0] = True   # only sources in block 0 are fresh
-    out = spmv_mod.spmv(
-        jnp.asarray(x), jnp.asarray(w), jnp.asarray(src), tiles,
-        jnp.asarray(active), v, eb=64, vb=32, interpret=True)
-    w_masked = w * (src < 32)
-    want = ref.fused_gather_segment_sum(
-        jnp.asarray(x), jnp.asarray(w_masked), jnp.asarray(src),
-        jnp.asarray(dst), v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-4)
+    out = _linear_sweep(x, w, src, dst, np.ones(e, bool), active[src // vb],
+                        v, eb=64, vb=vb)
+    want = _linear_reference(x, w * (src < vb), src, dst, v)
+    np.testing.assert_allclose(out, want, rtol=1e-4)
 
 
 # ------------------------------------------------------------------ flash

@@ -238,7 +238,7 @@ def test_hybrid_beats_2d_on_low_degree_tail():
 def test_hybrid_replication_bound_on_skewed_graph():
     """ISSUE 9 acceptance: on the skewed power-law graph the hybrid cut's
     replication is <= the 2D cut's at P=4."""
-    g = rmat(11, 12, seed=2)  # twitter-sim (benchmarks/common.py)
+    g = rmat(11, 12, seed=2)  # twitter-sim: power-law R-MAT
     s2 = pm.build_structure(g.src, g.dst, 4, partitioner="2d")
     sh = pm.build_structure(g.src, g.dst, 4, partitioner="hybrid")
     assert (sh.stats.replication_factor

@@ -6,6 +6,7 @@ sweep + combine + vprog + changed-mask derivation in one program) and the
 ring-pipelined mirror ship change SCHEDULES, never VALUES.  The 4-device
 SpmdExchange half lives in tests/spmd_check.py section (l).
 """
+import functools
 import importlib
 
 import numpy as np
@@ -124,11 +125,47 @@ def test_apply_plan_width_eligibility():
     assert apply_plan_of(gw, vp, send, "sum", default_msg=wide_dm) == "unfused"
 
 
+def _subjaxprs(val):
+    from jax.extend import core as jex
+    if isinstance(val, jex.ClosedJaxpr):
+        yield val.jaxpr
+    elif isinstance(val, jex.Jaxpr):
+        yield val
+    elif isinstance(val, (list, tuple)):
+        for v in val:
+            yield from _subjaxprs(v)
+
+
+def _count_home_shaped(jaxpr, shape2) -> int:
+    n = 0
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            if tuple(getattr(v.aval, "shape", ()))[:2] == shape2:
+                n += 1
+        for val in eqn.params.values():
+            for sub in _subjaxprs(val):
+                n += _count_home_shaped(sub, shape2)
+    return n
+
+
+def count_home_materializations(g: Graph, *, vprog, send_msg, gather,
+                                default_msg, skip_stale, fuse_apply) -> int:
+    """Number of home-vertex-shaped ([nl, v_blk, ...]) arrays one traced
+    superstep materializes.  Traced with kernel_mode="interpret" so the
+    fused sweeps stay single `pallas_call` equations — exactly what the
+    compiled HLO keeps VMEM-resident instead of round-tripping to HBM."""
+    fn = functools.partial(
+        pregel_mod._superstep, vprog=vprog, send_msg=send_msg, gather=gather,
+        default_msg=default_msg, skip_stale=skip_stale, changed_fn=None,
+        kernel_mode="interpret", use_cache=True, fuse_apply=fuse_apply)
+    jaxpr = jax.make_jaxpr(fn)(g)
+    nl, v_blk = g.s.home_vid.shape
+    return _count_home_shaped(jaxpr.jaxpr, (nl, v_blk))
+
+
 def test_fused_materializes_fewer_home_arrays():
     """The §2.3.2 HBM claim: one traced superstep materializes strictly
     fewer home-vertex-shaped arrays when the apply half fuses."""
-    jax.device_count()  # init the backend before launch.perf's XLA_FLAGS
-    from benchmarks.superstep_bench import count_home_materializations
     _, g = _cc_graph()
     kw = dict(vprog=_cc_vprog, send_msg=_cc_send, gather="min",
               default_msg={"m": IMAX}, skip_stale="out")

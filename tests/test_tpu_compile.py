@@ -9,6 +9,9 @@ takes a second or two.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
+
+The persistent compile cache that the chip benchmark turns on
+(`repro.utils.compile_cache`) is checked here too: where its directory goes.
 """
 import os
 
@@ -18,6 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import segment_sum, superstep, triplet
+from repro.utils import compile_cache
 
 EB = VB = 512
 P = 4
@@ -180,3 +184,18 @@ def test_superstep_names_for_v5e(one_chip):
     assert {"fused_triplet", "fused_apply"} <= calls
     ops = re.findall(r'op_name="([^"]*)"', text)
     assert any("/graphx.triplet_streams/" in o for o in ops)
+
+
+def test_compile_cache_directory(monkeypatch, tmp_path):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        default = os.path.join(root, ".jax_cache")
+        assert compile_cache.enable_compile_cache() == default
+        assert jax.config.jax_compilation_cache_dir == default
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
